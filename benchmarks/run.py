@@ -84,7 +84,10 @@ def main(argv=None) -> int:
 
     import importlib
 
+    from repro.backends.jax import enable_compile_cache
     from repro.core import SweepEngine
+
+    enable_compile_cache()
 
     only = set(args.only.split(",")) if args.only else None
     if only:

@@ -39,7 +39,8 @@ def main():
     cfg = get_smoke("llama3-8b")
     shape = ShapeConfig("mini_train", seq_len=128, global_batch=8,
                         kind="train")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     set_policy(mesh, "data")
 
     params_abs = abstract_params(cfg)

@@ -47,7 +47,7 @@ paid it; :func:`stepper_cache_size` still exposes the raw cache size.
 
 **Sharding**: with more than one visible device the batch row axis is
 partitioned across a 1-D ``("rows",)`` mesh with
-``jax.experimental.shard_map`` — each device runs the vmapped
+``jax.shard_map`` — each device runs the vmapped
 ``while_loop`` on its own row shard *independently* (no per-wave
 cross-device reduction: a shard whose rows finish early simply idles).
 The row axis is padded to a shard multiple by replicating the last row
@@ -75,7 +75,6 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.batchsim import (BatchArrays, GraphArrays,
@@ -365,7 +364,7 @@ def _run_batch_sharded(ctx: _Ctx, bounds, sched_t, sched_w, pol_state, *,
     """The stepper with the row axis sharded over ``n_shards`` devices.
 
     Each shard runs its own vmapped ``while_loop`` to completion with
-    no cross-device synchronization inside the loop (``check_rep`` off:
+    no cross-device synchronization inside the loop (``check_vma`` off:
     the outputs are row-partitioned by construction).  Callers pad the
     row axis to a multiple of ``n_shards`` first.
     """
@@ -374,10 +373,10 @@ def _run_batch_sharded(ctx: _Ctx, bounds, sched_t, sched_w, pol_state, *,
         redistribute=redistribute, max_steps=max_steps, impl=impl,
         interpret=interpret, stacked=stacked)
     rows = P("rows")
-    return shard_map(body, mesh=_row_mesh(n_shards),
-                     in_specs=(_ctx_specs(stacked), rows, rows, rows,
-                               rows),
-                     out_specs=rows, check_rep=False)(
+    return jax.shard_map(body, mesh=_row_mesh(n_shards),
+                         in_specs=(_ctx_specs(stacked), rows, rows, rows,
+                                   rows),
+                         out_specs=rows, check_vma=False)(
         ctx, bounds, sched_t, sched_w, pol_state)
 
 
@@ -604,22 +603,10 @@ class JaxBatchSimulator:
                     completed0=completed0, n_active=n_active,
                     dt=np.asarray(self.dt, ftype))
 
-    def dispatch(self) -> _Pending:
-        """Pack, pad, and *asynchronously* launch the compiled batch.
-
-        Returns as soon as the stepper is enqueued on the device(s):
-        the caller overlaps host work (packing the next bucket) with
-        the device compute and collects results later with
-        :meth:`fetch`.  The profile records the host packing time, the
-        dispatch wall-clock, and — when this dispatch is the first for
-        its jit cache key — the compile time it paid (a cache hit
-        dispatches in microseconds, so the dispatch wall *is* the
-        compile on a miss).  Attribution is per cache key, so
-        concurrent dispatches never charge a compile to the wrong
-        bucket.
-        """
-        prof = BucketProfile(rows=self.n_rows, devices=self.n_shards)
-        t0 = time.perf_counter()
+    def _pack(self) -> Tuple[tuple, Dict[str, object]]:
+        """The stepper's operands, as host arrays with the row axis
+        padded to the shard width, and its static config: what
+        :meth:`dispatch` hands to the jitted stepper."""
         self.policy.prepare(self)
         pol_state = {k: _to_device(v)
                      for k, v in self.policy.init_state(self).items()}
@@ -652,6 +639,27 @@ class JaxBatchSimulator:
             impl="pallas" if self.use_kernel else "ref",
             interpret=self.kernel_interpret,
             stacked=self.stacked)
+        return (ctx, _to_device(bounds), _to_device(sched_t),
+                _to_device(sched_w), pol_state), statics
+
+    def dispatch(self) -> _Pending:
+        """Pack, pad, and *asynchronously* launch the compiled batch.
+
+        Returns as soon as the stepper is enqueued on the device(s):
+        the caller overlaps host work (packing the next bucket) with
+        the device compute and collects results later with
+        :meth:`fetch`.  The profile records the host packing time, the
+        dispatch wall-clock, and — when this dispatch is the first for
+        its jit cache key — the compile time it paid (a cache hit
+        dispatches in microseconds, so the dispatch wall *is* the
+        compile on a miss).  Attribution is per cache key, so
+        concurrent dispatches never charge a compile to the wrong
+        bucket.
+        """
+        prof = BucketProfile(rows=self.n_rows, devices=self.n_shards)
+        t0 = time.perf_counter()
+        args, statics = self._pack()
+        ctx, bounds, sched_t, _, pol_state = args
         # The full jit identity of this dispatch: every traced operand
         # shape (geometry envelope, padded row count, schedule columns,
         # policy-state leaves) plus the static config.  Two dispatches
@@ -664,8 +672,6 @@ class JaxBatchSimulator:
              tuple(sorted((k, np.shape(v)) for k, v in pol_state.items())),
              self.n_shards, self.policy.name)
             + tuple(sorted(statics.items())))
-        args = (ctx, _to_device(bounds), _to_device(sched_t),
-                _to_device(sched_w), pol_state)
         t1 = time.perf_counter()
         prof.pack_s = t1 - t0
         prof.compiled = _claim_cache_key(prof.cache_key)

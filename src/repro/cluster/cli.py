@@ -113,6 +113,10 @@ def cmd_generate(args: argparse.Namespace) -> int:
 
 def cmd_run(args: argparse.Namespace) -> int:
     """The ``run`` subcommand."""
+    if args.executor == "jax":
+        from ..backends.jax import enable_compile_cache
+
+        enable_compile_cache()
     trace = load_arrivals(args.trace)
     policies = [p.strip() for p in args.policies.split(",") if p.strip()]
     bound = args.bound_w if args.bound_w is not None else \

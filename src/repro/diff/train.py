@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backends.jax import enable_compile_cache
 from repro.core.power import (NodeSpec, homogeneous_cluster,
                               min_feasible_cluster_bound,
                               max_useful_cluster_bound)
@@ -146,6 +147,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--out", default=None,
                     help="checkpoint path (default: print only)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     params, meta = train_policy(seed=args.seed, steps=args.steps,
                                 lr=args.lr, quick=args.quick)
     if args.out:

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 For every runnable (architecture x input shape) cell this lowers AND
@@ -17,8 +14,11 @@ allocation).  It records, per cell:
 written to results/dryrun/<arch>__<shape>__<mesh>.json for the roofline
 report (benchmarks/roofline_report.py reads these artifacts).
 
-NOTE the XLA_FLAGS line above MUST precede every other import — jax locks
-the host device count at first backend initialisation.
+This is a CPU-only compile tool: :func:`main` pins JAX to the CPU
+platform with 512 host devices before any backend initialises, so it
+never takes an accelerator the machine may have (jax locks the platform
+and the host device count at first backend initialisation — run it in
+a fresh interpreter).
 """
 
 import argparse
@@ -223,7 +223,13 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     return record
 
 
+#: Host devices the production meshes are built from (2 x 16 x 16).
+N_HOST_DEVICES = 512
+
+
 def main(argv=None):
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", N_HOST_DEVICES)
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

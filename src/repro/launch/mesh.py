@@ -17,13 +17,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_smoke_mesh():
     """Whatever devices exist locally, as a 1D (data,) mesh."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=_auto(("data",)))
+
+
+def _auto(axes) -> tuple:
+    """Auto axis types: ``jax.make_mesh`` defaults to Explicit axes,
+    which ``with_sharding_constraint`` on named specs rejects."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 def dp_axes(mesh) -> Union[str, Tuple[str, ...]]:

@@ -218,6 +218,10 @@ def main(argv=None) -> int:
     llm.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
 
+    if args.trace_corpus is None or args.executor == "jax":
+        from ..backends.jax import enable_compile_cache
+
+        enable_compile_cache()
     if args.trace_corpus is not None:
         return _serve_sweep(args)
     return _serve_llm(args)

@@ -216,7 +216,6 @@ def decode_attend_seqsharded(q: jnp.ndarray, k_cache: jnp.ndarray,
     pos scalar.  Requires an active sharding policy; returns
     (out (B,1,H,dh), k_cache, v_cache).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .sharding import _LOCAL
@@ -271,7 +270,7 @@ def decode_attend_seqsharded(q: jnp.ndarray, k_cache: jnp.ndarray,
         out = jnp.moveaxis(out, 3, 1).reshape(Bl, 1, H, dh)
         return out.astype(q_l.dtype), kc, vc
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(b_spec, None, None, None),
                   P(b_spec, mdl, None, None), P(b_spec, mdl, None, None),
@@ -279,7 +278,7 @@ def decode_attend_seqsharded(q: jnp.ndarray, k_cache: jnp.ndarray,
                   P()),
         out_specs=(P(b_spec, None, None, None),
                    P(b_spec, mdl, None, None), P(b_spec, mdl, None, None)),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_cache, v_cache, new_k, new_v, pos)
 
 

@@ -198,7 +198,8 @@ class TestCheckpoint:
         mgr = CheckpointManager(str(tmp_path))
         tree = self.make_tree(5.0)
         mgr.save(2, tree)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         sh = jax.tree_util.tree_map(
             lambda _: NamedSharding(mesh, P()), tree)
         restored, step, _ = mgr.restore(self.make_tree(0.0), shardings=sh)
