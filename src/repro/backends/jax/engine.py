@@ -425,6 +425,26 @@ def _pad_rows(pad: int, *arrays):
                  for a in arrays)
 
 
+def wave_counts(steps: np.ndarray, n_rows: int,
+                n_shards: int) -> Tuple[int, int, int]:
+    """``(waves, row_waves, row_slots)`` of one fetched batch from its
+    rows' ``steps`` (the row axis as dispatched, phantom rows included).
+
+    Each shard runs one ``while_loop`` over its contiguous block of
+    rows until its slowest row is done, so ``waves`` — the lockstep
+    iterations — is the most ``steps`` of each shard's real rows,
+    summed over the shards; ``row_waves`` is the sum over the real
+    rows, and ``row_slots`` each shard's real rows times its waves,
+    summed.  The shard-padding phantom rows (after ``n_rows``) count in
+    none of them.
+    """
+    per = len(steps) // n_shards
+    real = steps[:n_rows]
+    shards = [real[s:s + per] for s in range(0, n_rows, per)]
+    return (sum(int(b.max()) for b in shards), int(real.sum()),
+            sum(len(b) * int(b.max()) for b in shards))
+
+
 def _to_device(x):
     """Normalize dtypes host-side; the jit boundary does the transfer."""
     a = np.asarray(x)
@@ -642,7 +662,7 @@ class JaxBatchSimulator:
         return (ctx, _to_device(bounds), _to_device(sched_t),
                 _to_device(sched_w), pol_state), statics
 
-    def dispatch(self) -> _Pending:
+    def dispatch(self, bucket: str = "?") -> _Pending:
         """Pack, pad, and *asynchronously* launch the compiled batch.
 
         Returns as soon as the stepper is enqueued on the device(s):
@@ -654,45 +674,50 @@ class JaxBatchSimulator:
         dispatches in microseconds, so the dispatch wall *is* the
         compile on a miss).  Attribution is per cache key, so
         concurrent dispatches never charge a compile to the wrong
-        bucket.
+        bucket.  ``bucket`` labels the profile and the regions of
+        this batch's dispatch and fetch (``repro.engine.*``, see
+        :func:`repro.obs.trace.region`), whichever thread runs them.
         """
-        prof = BucketProfile(rows=self.n_rows, devices=self.n_shards)
-        t0 = time.perf_counter()
-        args, statics = self._pack()
-        ctx, bounds, sched_t, _, pol_state = args
-        # The full jit identity of this dispatch: every traced operand
-        # shape (geometry envelope, padded row count, schedule columns,
-        # policy-state leaves) plus the static config.  Two dispatches
-        # share a compiled stepper iff their keys are equal, so the
-        # per-key compile attribution below is exact even when batches
-        # dispatch concurrently.
-        prof.cache_key = (
-            (ctx.work_pad.shape, ctx.node_seq.shape,
-             np.shape(bounds), np.shape(sched_t),
-             tuple(sorted((k, np.shape(v)) for k, v in pol_state.items())),
-             self.n_shards, self.policy.name)
-            + tuple(sorted(statics.items())))
-        t1 = time.perf_counter()
-        prof.pack_s = t1 - t0
+        prof = BucketProfile(bucket=bucket, rows=self.n_rows,
+                             devices=self.n_shards)
+        # The profile's timers run inside the regions, so they time the
+        # same work with tracing on or off.
+        with obs_trace.region("pack", "engine", chrome="pack",
+                              bucket=bucket, rows=self.n_rows,
+                              devices=self.n_shards):
+            t0 = time.perf_counter()
+            args, statics = self._pack()
+            ctx, bounds, sched_t, _, pol_state = args
+            # The full jit identity of this dispatch: every traced
+            # operand shape (geometry envelope, padded row count,
+            # schedule columns, policy-state leaves) plus the static
+            # config.  Two dispatches share a compiled stepper iff
+            # their keys are equal, so the per-key compile attribution
+            # below is exact even when batches dispatch concurrently.
+            prof.cache_key = (
+                (ctx.work_pad.shape, ctx.node_seq.shape,
+                 np.shape(bounds), np.shape(sched_t),
+                 tuple(sorted((k, np.shape(v))
+                              for k, v in pol_state.items())),
+                 self.n_shards, self.policy.name)
+                + tuple(sorted(statics.items())))
+            prof.pack_s = time.perf_counter() - t0
         prof.compiled = _claim_cache_key(prof.cache_key)
-        if self.n_shards > 1:
-            out = _run_batch_sharded(*args, n_shards=self.n_shards,
-                                     **statics)
-        else:
-            out = _run_batch(*args, **statics)
-        prof.dispatch_s = time.perf_counter() - t1
+        # Regions are host-side only: they cannot perturb the jit key.
+        with obs_trace.region("dispatch", "engine",
+                              chrome="compile" if prof.compiled
+                              else "dispatch",
+                              bucket=bucket, rows=self.n_rows,
+                              devices=self.n_shards,
+                              compiled=prof.compiled):
+            t1 = time.perf_counter()
+            if self.n_shards > 1:
+                out = _run_batch_sharded(*args, n_shards=self.n_shards,
+                                         **statics)
+            else:
+                out = _run_batch(*args, **statics)
+            prof.dispatch_s = time.perf_counter() - t1
         prof.compile_s = prof.dispatch_s if prof.compiled else 0.0
-        # Trace spans reuse the profile's own measurements (one timer,
-        # two consumers) — tracing cannot skew what the profile reports
-        # and, being host-side only, cannot perturb the jit cache key.
-        if obs_trace.enabled():
-            args = {"rows": self.n_rows, "devices": self.n_shards}
-            obs_trace.complete("pack", t0, prof.pack_s, cat="engine",
-                               track="engine", args=args)
-            obs_trace.complete("compile" if prof.compiled else "dispatch",
-                               t1, prof.dispatch_s, cat="engine",
-                               track="engine",
-                               args=dict(args, compiled=prof.compiled))
         return _Pending(out=out, profile=prof)
 
     def fetch(self, pending: _Pending) -> List[SimResult]:
@@ -701,23 +726,28 @@ class JaxBatchSimulator:
         The whole output pytree comes back in ONE fused device-to-host
         transfer (``jax.device_get``) — never one sync per field — and
         shard-padding phantom rows are trimmed before any bookkeeping.
+        The profile counts the batch's waves from the rows' ``steps``.
         """
         prof = pending.profile
-        t0 = time.perf_counter()
-        jax.block_until_ready(pending.out)
-        t1 = time.perf_counter()
-        prof.run_s = t1 - t0
-        out = _device_get(pending.out)
-        prof.transfer_s = time.perf_counter() - t1
-        if obs_trace.enabled():
-            args = {"rows": self.n_rows, "devices": self.n_shards}
-            obs_trace.complete("run", t0, prof.run_s, cat="engine",
-                               track="engine", args=args)
-            obs_trace.complete("transfer", t1, prof.transfer_s,
-                               cat="engine", track="engine", args=args)
-        out = {k: np.asarray(v)[:self.n_rows] for k, v in out.items()}
-        self._check_failures(out)
-        return self._results(out)
+        args = {"bucket": prof.bucket, "rows": self.n_rows,
+                "devices": self.n_shards}
+        with obs_trace.region("wait", "engine", chrome="run", **args):
+            t0 = time.perf_counter()
+            jax.block_until_ready(pending.out)
+            prof.run_s = time.perf_counter() - t0
+        with obs_trace.region("transfer", "engine", chrome="transfer",
+                              **args):
+            t1 = time.perf_counter()
+            out = _device_get(pending.out)
+            prof.transfer_s = time.perf_counter() - t1
+        prof.waves, prof.row_waves, prof.row_slots = wave_counts(
+            np.asarray(out["steps"]), self.n_rows, self.n_shards)
+        with obs_trace.region("results", "engine", waves=prof.waves,
+                              row_waves=prof.row_waves,
+                              row_slots=prof.row_slots, **args):
+            out = {k: np.asarray(v)[:self.n_rows] for k, v in out.items()}
+            self._check_failures(out)
+            return self._results(out)
 
     def run(self) -> List[SimResult]:
         """Dispatch and immediately fetch (the synchronous facade)."""

@@ -17,7 +17,11 @@ with the four phases of its life separated out:
   (under the pipeline this is the wait *remaining* at fetch time, i.e.
   device time not hidden behind host work);
 * **transfer** — the single fused device-to-host fetch of the whole
-  output pytree.
+  output pytree;
+
+and its **waves** — the lockstep while-loop iterations the device ran
+and the rows' own waves, counted at fetch from the ``steps`` the
+stepper already returns (device time over waves is the cost of one).
 
 :class:`SweepProfile` aggregates the buckets of one sweep and renders
 the one-line summary that ``SweepResult.backend_summary()`` appends.
@@ -47,6 +51,15 @@ class BucketProfile:
     compile_s: float = 0.0
     run_s: float = 0.0
     transfer_s: float = 0.0
+    #: lockstep while-loop iterations: per shard the most waves of its
+    #: real rows, summed over shards (0 until fetched)
+    waves: int = 0
+    #: waves summed over the real rows (at most ``row_slots``)
+    row_waves: int = 0
+    #: per shard its real rows times its lockstep waves, summed: the
+    #: row-waves stepped, a row's own or idle behind the slowest row
+    #: (``rows * waves`` on one shard)
+    row_slots: int = 0
 
     def to_dict(self) -> Dict[str, object]:
         """Flat JSON-ready payload (BENCH records embed these)."""
@@ -58,6 +71,8 @@ class BucketProfile:
             "pack_s": self.pack_s, "dispatch_s": self.dispatch_s,
             "compile_s": self.compile_s, "run_s": self.run_s,
             "transfer_s": self.transfer_s,
+            "waves": self.waves, "row_waves": self.row_waves,
+            "row_slots": self.row_slots,
         }
 
 

@@ -502,11 +502,18 @@ class AssignmentCache:
         return assignment
 
 
+def bucket_label(tag: str, shared: bool, pad_dims: tuple) -> str:
+    """A bucket's label: ``jax#0:shared``, ``jax#1.0:padded(N8,J64)``."""
+    return (f"{tag}:shared" if shared else
+            f"{tag}:padded(N{pad_dims[0]},J{pad_dims[1]})")
+
+
 def build_batch_sim(backend: str, scens: List[Scenario],
                     assignments: List[Optional[PowerAssignment]],
                     shared: bool, pad_dims: tuple, *,
                     vector_dt: float = 0.05,
-                    shard_devices: Optional[int] = None):
+                    shard_devices: Optional[int] = None,
+                    label: str = "?"):
     """Construct the batch simulator for one planned bucket.
 
     ``scens`` must share a :func:`bucket_key`; ``shared`` selects the
@@ -515,8 +522,17 @@ def build_batch_sim(backend: str, scens: List[Scenario],
     ``"jax"`` — the returned simulator is a
     :class:`~repro.core.batchsim.BatchSimulator` or
     :class:`~repro.backends.jax.engine.JaxBatchSimulator` accordingly
-    (only the latter has the dispatch/fetch split).
+    (only the latter has the dispatch/fetch split).  The build (graph
+    arrays, DAG validation, policy lookup) is the ``repro.sweep.build``
+    region, labelled ``label``.
     """
+    with obs_trace.region("build", "sweep", bucket=label, rows=len(scens)):
+        return _build_batch_sim(backend, scens, assignments, shared,
+                                pad_dims, vector_dt, shard_devices)
+
+
+def _build_batch_sim(backend, scens, assignments, shared, pad_dims,
+                     vector_dt, shard_devices):
     first = scens[0]
     kwargs = {}
     if first.policy in ILP_POLICIES:
@@ -636,7 +652,8 @@ class SweepEngine:
         one = self._run_one
 
         if self.executor in self.BATCHED_EXECUTORS:
-            return self._run_batched(scenarios, self.executor)
+            with obs_trace.region("run", "sweep", scenarios=len(scenarios)):
+                return self._run_batched(scenarios, self.executor)
         if self.executor == "serial" or len(scenarios) <= 1:
             return SweepResult([one(s) for s in scenarios])
         if self.executor == "process":
@@ -685,35 +702,38 @@ class SweepEngine:
                     ) -> tuple:
         return bucket_key(backend, s, dims_cache)
 
+    def _shared(self, scens: List[Scenario]) -> bool:
+        """One graph and one cluster: the zero-padding shared layout."""
+        return (len({id(s.graph) for s in scens}) == 1
+                and len({self._specs_sig(s.specs) for s in scens}) == 1)
+
     def _make_batch_sim(self, backend: str, scens: List[Scenario],
                         assignments: List[Optional[PowerAssignment]],
-                        shared: bool, pad_dims: tuple):
+                        shared: bool, pad_dims: tuple, label: str = "?"):
         return build_batch_sim(backend, scens, assignments, shared,
                                pad_dims, vector_dt=self.vector_dt,
-                               shard_devices=self.shard_devices)
+                               shard_devices=self.shard_devices,
+                               label=label)
 
     def _run_batched(self, scenarios: Sequence[Scenario],
                      requested: str) -> SweepResult:
         records: List[Optional[SweepRecord]] = [None] * len(scenarios)
-        plan_t0 = time.perf_counter()
-        plans = [self._plan_backend(s, requested) for s in scenarios]
-        groups: Dict[tuple, List[int]] = {}
-        leftovers: List[int] = []
-        dims_cache: Dict[tuple, tuple] = {}
-        for k, s in enumerate(scenarios):
-            backend, _ = plans[k]
-            if backend in self.BATCHED_EXECUTORS:
-                groups.setdefault(self._bucket_key(backend, s, dims_cache),
-                                  []).append(k)
-            else:
-                leftovers.append(k)
-        if obs_trace.enabled():
-            obs_trace.complete("plan", plan_t0,
-                               time.perf_counter() - plan_t0, cat="sweep",
-                               track="engine",
-                               args={"scenarios": len(scenarios),
-                                     "buckets": len(groups),
-                                     "leftovers": len(leftovers)})
+        with obs_trace.region("plan", "sweep", chrome="plan",
+                              chrome_track="engine",
+                              scenarios=len(scenarios)) as plan_region:
+            plans = [self._plan_backend(s, requested) for s in scenarios]
+            groups: Dict[tuple, List[int]] = {}
+            leftovers: List[int] = []
+            dims_cache: Dict[tuple, tuple] = {}
+            for k, s in enumerate(scenarios):
+                backend, _ = plans[k]
+                if backend in self.BATCHED_EXECUTORS:
+                    groups.setdefault(
+                        self._bucket_key(backend, s, dims_cache),
+                        []).append(k)
+                else:
+                    leftovers.append(k)
+            plan_region.note(buckets=len(groups), leftovers=len(leftovers))
 
         profile = None
         jax_align = 1
@@ -736,12 +756,14 @@ class SweepEngine:
 
         def finish(batch_idx, results, t0, backend, bucket):
             per_cell = (time.perf_counter() - t0) / len(batch_idx)
-            for k, result in zip(batch_idx, results):
-                records[k] = SweepRecord(scenarios[k], result,
-                                         elapsed_s=per_cell,
-                                         backend=backend,
-                                         fallback_reason=plans[k][1],
-                                         bucket=bucket)
+            with obs_trace.region("records", "sweep", bucket=bucket,
+                                  rows=len(batch_idx)):
+                for k, result in zip(batch_idx, results):
+                    records[k] = SweepRecord(scenarios[k], result,
+                                             elapsed_s=per_cell,
+                                             backend=backend,
+                                             fallback_reason=plans[k][1],
+                                             bucket=bucket)
 
         def fail(batch_idx, err, t0, backend, bucket):
             per_cell = (time.perf_counter() - t0) / len(batch_idx)
@@ -770,13 +792,20 @@ class SweepEngine:
             # Shared setup first: a failing ILP solve is a per-scenario
             # failure, not a batch abort.  Solves run on a thread pool —
             # the solver is a subprocess, so threads give the same real
-            # concurrency the thread executor has always had.
-            if first.policy in self._ILP_POLICIES and len(idxs) > 1:
-                with _futures.ThreadPoolExecutor(
-                        max_workers=self.max_workers) as pool:
-                    solved = list(pool.map(solve, idxs))
-            else:
-                solved = [solve(k) for k in idxs]
+            # concurrency the thread executor has always had.  The
+            # region carries the label the bucket has when it runs as
+            # one chunk.
+            tag = f"{backend}#{bnum}"
+            group_shared = self._shared([scenarios[k] for k in idxs])
+            with obs_trace.region(
+                    "solve", "sweep", rows=len(idxs),
+                    bucket=bucket_label(tag, group_shared, pad_dims)):
+                if first.policy in self._ILP_POLICIES and len(idxs) > 1:
+                    with _futures.ThreadPoolExecutor(
+                            max_workers=self.max_workers) as pool:
+                        solved = list(pool.map(solve, idxs))
+                else:
+                    solved = [solve(k) for k in idxs]
             live: List[int] = []
             assign_by_k: Dict[int, Optional[PowerAssignment]] = {}
             for k, assignment, err in solved:
@@ -801,21 +830,16 @@ class SweepEngine:
                 t0 = time.perf_counter()
                 scens = [scenarios[k] for k in batch_idx]
                 assignments = [assign_by_k[k] for k in batch_idx]
-                shared = (len({id(s.graph) for s in scens}) == 1
-                          and len({self._specs_sig(s.specs)
-                                   for s in scens}) == 1)
-                tag = f"{backend}#{bnum}" + \
-                    (f".{ci}" if len(chunks) > 1 else "")
-                bucket = (f"{tag}:shared" if shared else
-                          f"{tag}:padded(N{pad_dims[0]},"
-                          f"J{pad_dims[1]})")
+                shared = group_shared or self._shared(scens)
+                bucket = bucket_label(
+                    tag + (f".{ci}" if len(chunks) > 1 else ""), shared,
+                    pad_dims)
                 try:
                     sim = self._make_batch_sim(backend, scens,
                                                assignments, shared,
-                                               pad_dims)
+                                               pad_dims, bucket)
                     if backend == "jax":
-                        pending = sim.dispatch()
-                        pending.profile.bucket = bucket
+                        pending = sim.dispatch(bucket)
                         # Profile recording is unconditional from the
                         # moment a bucket dispatches: a failed fetch
                         # must still surface the bucket in
@@ -826,13 +850,6 @@ class SweepEngine:
                         if self.pipeline:
                             in_flight.append(
                                 (sim, pending, batch_idx, bucket, t0))
-                            if obs_trace.enabled():
-                                obs_trace.complete(
-                                    "bucket:dispatch", t0,
-                                    time.perf_counter() - t0, cat="sweep",
-                                    track="engine",
-                                    args={"bucket": bucket,
-                                          "rows": len(batch_idx)})
                             continue
                         results = sim.fetch(pending)
                     else:
@@ -855,16 +872,9 @@ class SweepEngine:
         # device work finishes, then pull its whole output pytree in
         # one transfer.  (Profiles were already recorded at dispatch.)
         for sim, pending, batch_idx, bucket, t0 in in_flight:
-            fetch_t0 = time.perf_counter()
             try:
                 results = sim.fetch(pending)
                 finish(batch_idx, results, t0, "jax", bucket)
-                if obs_trace.enabled():
-                    obs_trace.complete(
-                        "bucket:fetch", fetch_t0,
-                        time.perf_counter() - fetch_t0, cat="sweep",
-                        track="engine",
-                        args={"bucket": bucket, "rows": len(batch_idx)})
             except Exception as e:  # noqa: BLE001
                 fail(batch_idx, f"{type(e).__name__}: {e}", t0, "jax",
                      bucket)

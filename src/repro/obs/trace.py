@@ -30,6 +30,14 @@ Enabling: inject a :class:`Tracer` with :func:`install`, or set
 the tracer is installed on first import and the file written at exit
 (see :func:`configure_from_env`).
 
+**The profiler's clock.**  :func:`region` marks a point of host work
+(one bucket's build, pack, dispatch, wait...) as a
+``jax.profiler.TraceAnnotation`` named ``repro.<track>.<name>`` while
+JAX is loaded, so a ``jax.profiler`` trace shows it on the host lines
+beside the device's operations; with a :class:`Tracer` installed it
+also emits the call site's Chrome event.  This module never imports
+JAX itself.
+
 Example::
 
     >>> from repro.obs import trace
@@ -50,6 +58,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -77,6 +86,9 @@ class _NoopSpan:
 
     def __exit__(self, *exc) -> bool:
         return False
+
+    def note(self, **args) -> None:
+        """What :meth:`_Region.note` does when nothing records."""
 
 
 _NOOP_SPAN = _NoopSpan()
@@ -110,6 +122,37 @@ class _Span:
                               cat=self._cat, track=self._track,
                               lane=self._lane, args=self._args)
         return False
+
+
+class _Region:
+    """An open :func:`region`: a profiler annotation, a Chrome span, or
+    both, entered and left together."""
+
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, annotation, span: Optional[_Span]):
+        self._annotation = annotation
+        self._span = span
+
+    def __enter__(self) -> "_Region":
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._span is not None:
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
+
+    def note(self, **args) -> None:
+        """Add ``args`` to the Chrome event, for what is known only at
+        the end of the region (the profiler's args are fixed at entry)."""
+        if self._span is not None:
+            self._span._args.update(args)
 
 
 class Tracer:
@@ -322,6 +365,52 @@ def span(name: str, cat: str = "", track: Optional[str] = None,
     if t is None:
         return _NOOP_SPAN
     return t.span(name, cat=cat, track=track, lane=lane, args=args)
+
+
+#: ``jax.profiler.TraceAnnotation`` once JAX is loaded; looked up, never
+#: imported, so this module stays importable without JAX.
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        _ANNOTATION = getattr(sys.modules.get("jax.profiler"),
+                              "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+def region(name: str, track: str, chrome: Optional[str] = None,
+           chrome_track: Optional[str] = None, **args):
+    """A span at a point of work, on the profiler's clock and the
+    tracer's: use it as a context manager.
+
+    While JAX is loaded it enters ``jax.profiler.TraceAnnotation(
+    "repro.<track>.<name>", **args)``: the args become stats of the
+    event in the profiler's trace (a ``#`` in a string arg is written
+    ``%23``, since the annotation's encoding ends at ``#``), while the
+    profiler records; with it off the check costs a fraction of a
+    microsecond.  Regions mark points of work (a bucket's pack, its
+    dispatch), never a wave or a job.  While a :class:`Tracer` is
+    installed and ``chrome`` is given, it also emits the Chrome
+    complete event ``chrome`` on ``chrome_track`` (default ``track``)
+    with category ``track`` and the same args; :meth:`_Region.note`
+    adds args known only at the end.  With neither, it is the shared
+    no-op.
+    """
+    ann = _annotation()
+    if ann is not None and not ann.is_enabled():
+        ann = None
+    t = _TRACER if chrome is not None else None
+    if ann is None and t is None:
+        return _NOOP_SPAN
+    if ann is not None:
+        ann = ann(f"repro.{track}.{name}",
+                  **{k: v.replace("#", "%23") if isinstance(v, str) else v
+                     for k, v in args.items()})
+    span = None if t is None else t.span(
+        chrome, cat=track, track=chrome_track or track, args=args)
+    return _Region(ann, span)
 
 
 def complete(name: str, t0: float, dur_s: float, cat: str = "",

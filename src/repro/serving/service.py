@@ -604,11 +604,10 @@ class SweepService:
                 sim = build_batch_sim(
                     bucket.backend, scens, assignments, False,
                     bucket.pad_dims, vector_dt=self.vector_dt,
-                    shard_devices=self.shard_devices)
+                    shard_devices=self.shard_devices, label=flush.label)
                 self._c_phantom.inc(pad)
                 if bucket.backend == "jax":
-                    pending = sim.dispatch()
-                    pending.profile.bucket = flush.label
+                    pending = sim.dispatch(flush.label)
                     # recorded at dispatch, unconditionally: a failed
                     # fetch must still show up in the profile
                     self.profile.add(pending.profile)

@@ -181,6 +181,39 @@ class TestDispatchPipeline:
                                                     rel=1e-5)
         assert pending.profile.transfer_s >= 0.0
 
+    def test_one_row_bucket_counts_its_waves(self):
+        sim = JaxBatchSimulator(listing2_graph(), homogeneous_cluster(3),
+                                [6.0])
+        pending = sim.dispatch("l2:one")
+        sim.fetch(pending)
+        prof = pending.profile
+        assert prof.bucket == "l2:one"
+        assert prof.waves > 0
+        assert prof.waves == prof.row_waves == prof.row_slots
+        d = prof.to_dict()
+        assert (d["waves"], d["row_waves"], d["row_slots"]) \
+            == (prof.waves, prof.row_waves, prof.row_slots)
+
+    def test_bucket_waves_are_its_rows_steps(self):
+        """Each row steps as it would alone: the bucket's lockstep waves
+        are its slowest row's, and its row-waves their sum."""
+        g, specs = listing2_graph(), homogeneous_cluster(3)
+        bounds = [2.5, 6.0, 12.0]
+        alone = []
+        for b in bounds:
+            sim = JaxBatchSimulator(g, specs, [b], policy="oracle")
+            pending = sim.dispatch()
+            sim.fetch(pending)
+            alone.append(pending.profile.waves)
+        sim = JaxBatchSimulator(g, specs, bounds, policy="oracle")
+        pending = sim.dispatch()
+        sim.fetch(pending)
+        prof = pending.profile
+        assert prof.waves == max(alone)
+        assert prof.row_waves == sum(alone)
+        assert prof.row_slots == len(bounds) * prof.waves
+        assert prof.row_waves <= prof.rows * prof.waves
+
     @pytest.mark.parametrize("pipeline", [True, False])
     def test_failed_fetch_keeps_profile(self, monkeypatch, pipeline):
         """Profiles are recorded at *dispatch*: a bucket whose fetch

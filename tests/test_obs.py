@@ -402,3 +402,173 @@ class TestJaxTracing:
         assert "pack" in names
         # every jit compile shows up as exactly one "compile" span
         assert names.count("compile") == prof.compiles
+
+
+# -------------------------------------- regions on the profiler's clock
+#: the per-bucket regions of a jax sweep, and the once-per-run ones
+BUCKET_REGIONS = ("repro.sweep.solve", "repro.sweep.build",
+                  "repro.engine.pack", "repro.engine.dispatch",
+                  "repro.engine.wait", "repro.engine.transfer",
+                  "repro.engine.results", "repro.sweep.records")
+RUN_REGIONS = ("repro.sweep.run", "repro.sweep.plan")
+
+
+@pytest.fixture(scope="module")
+def profiled_sweep(tmp_path_factory):
+    """A two-bucket jax sweep under ``jax.profiler``: its ``repro.*``
+    host events ``(name, start_ns, end_ns, line, stats)`` and its
+    profile."""
+    from repro.backends.jax import HAS_JAX
+
+    if not HAS_JAX:
+        pytest.skip("jax not installed")
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    cells = grid(bounds=(6.0, 9.0), policies=("equal-share", "oracle"))
+    engine = SweepEngine(executor="jax")
+    engine.run(cells)                       # compile outside the trace
+    out = tmp_path_factory.mktemp("xplane")
+    jax.profiler.start_trace(str(out))
+    try:
+        result = engine.run(cells)
+    finally:
+        jax.profiler.stop_trace()
+    assert not result.failures
+    path, = glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for n, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    events.append((ev.name, int(ev.start_ns),
+                                   int(ev.end_ns), (plane.name, n),
+                                   dict(ev.stats)))
+    return events, result.profile
+
+
+class TestProfilerRegions:
+    def test_each_region_once_per_bucket(self, profiled_sweep):
+        events, profile = profiled_sweep
+        names = [e[0] for e in events]
+        buckets = len(profile.buckets)
+        assert buckets == 2
+        for name in BUCKET_REGIONS:
+            assert names.count(name) == buckets, name
+        for name in RUN_REGIONS:
+            assert names.count(name) == 1, name
+        # O(buckets), never O(cells) or O(waves)
+        assert len(events) <= 12 * buckets + 4
+
+    def test_regions_nest_under_the_run_and_carry_the_label(
+            self, profiled_sweep):
+        events, profile = profiled_sweep
+        (_, r0, r1, line, args), = [e for e in events
+                                    if e[0] == "repro.sweep.run"]
+        assert args["scenarios"] == 4
+        labels = {b.bucket.replace("#", "%23") for b in profile.buckets}
+        for name, a, b, ln, stats in events:
+            assert r0 <= a <= b <= r1 and ln == line, name
+            if name in BUCKET_REGIONS:
+                assert stats["bucket"] in labels, (name, stats)
+                assert stats["rows"] == 2
+        waves = {e[4]["bucket"]: e[4] for e in events
+                 if e[0] == "repro.engine.results"}
+        for b in profile.buckets:
+            got = waves[b.bucket.replace("#", "%23")]
+            assert (got["waves"], got["row_waves"], got["row_slots"]) \
+                == (b.waves, b.row_waves, b.row_slots)
+
+    def test_chrome_events_keep_their_names(self, tracer):
+        from repro.backends.jax import HAS_JAX
+
+        if not HAS_JAX:
+            pytest.skip("jax not installed")
+        result = SweepEngine(executor="jax").run(grid())
+        assert not result.failures
+        xs = [e for e in tracer.events() if e["ph"] == "X"]
+        names = {e["name"] for e in xs}
+        assert {"plan", "pack", "run", "transfer"} <= names
+        assert names & {"compile", "dispatch"}
+        assert not names & {"bucket:dispatch", "bucket:fetch"}
+        plan, = [e for e in xs if e["name"] == "plan"]
+        assert plan["args"] == {"scenarios": 2, "buckets": 1,
+                                "leftovers": 0}
+        assert plan["cat"] == "sweep" and \
+            plan["pid"] == tracer.track_ids()["engine"]
+        label = result.profile.buckets[0].bucket
+        assert all(e["args"]["bucket"] == label for e in xs
+                   if e["name"] in ("pack", "run", "transfer"))
+
+    def test_region_count_is_per_bucket_with_the_profiler_off(
+            self, monkeypatch):
+        from repro.backends.jax import HAS_JAX
+
+        if not HAS_JAX:
+            pytest.skip("jax not installed")
+        opened = []
+        real = trace.region
+
+        def counting(name, track, *a, **kw):
+            opened.append(f"repro.{track}.{name}")
+            return real(name, track, *a, **kw)
+
+        monkeypatch.setattr(trace, "region", counting)
+        engine = SweepEngine(executor="jax")
+        per_cells = []
+        for bounds in ((6.0, 9.0), (2.5, 4.0, 6.0, 9.0, 12.0, 15.0)):
+            opened.clear()
+            result = engine.run(grid(bounds=bounds))
+            assert not result.failures and len(result.profile.buckets) == 1
+            per_cells.append(sorted(opened))
+        assert per_cells[0] == per_cells[1]
+        assert sorted(per_cells[0]) == sorted(BUCKET_REGIONS + RUN_REGIONS)
+
+
+class TestRegionHelper:
+    def test_noop_without_jax_or_tracer(self, monkeypatch):
+        monkeypatch.setattr(trace, "_annotation", lambda: None)
+        assert trace.region("pack", "engine", chrome="pack",
+                            rows=2) is trace._NOOP_SPAN
+        with trace.region("pack", "engine") as r:
+            r.note(rows=3)
+
+    def test_chrome_event_only_where_named(self, tracer, monkeypatch):
+        monkeypatch.setattr(trace, "_annotation", lambda: None)
+        assert trace.region("results", "engine",
+                            bucket="a") is trace._NOOP_SPAN
+        with trace.region("plan", "sweep", chrome="plan",
+                          chrome_track="engine", scenarios=4) as r:
+            r.note(buckets=2)
+        ev, = [e for e in tracer.events() if e["ph"] == "X"]
+        assert (ev["name"], ev["cat"]) == ("plan", "sweep")
+        assert ev["pid"] == tracer.track_ids()["engine"]
+        assert ev["args"] == {"scenarios": 4, "buckets": 2}
+
+    def test_annotation_gets_the_profiler_name_and_escaped_args(
+            self, monkeypatch):
+        entered = []
+
+        class Annotation:
+            @staticmethod
+            def is_enabled():
+                return True
+
+            def __init__(self, name, **args):
+                self.seen = (name, args)
+
+            def __enter__(self):
+                entered.append(self.seen)
+
+            def __exit__(self, *exc):
+                entered.append("exit")
+
+        monkeypatch.setattr(trace, "_annotation", lambda: Annotation)
+        with trace.region("pack", "engine", chrome="pack",
+                          bucket="jax#0:shared", rows=2):
+            pass
+        assert entered == [("repro.engine.pack",
+                            {"bucket": "jax%230:shared", "rows": 2}),
+                           "exit"]

@@ -135,6 +135,31 @@ class TestPlanner:
                 b.result.makespan, abs=1e-6)
 
 
+class TestWaveCounts:
+    """Waves per bucket from the rows' ``steps`` (no mesh required)."""
+
+    def test_phantom_shard_rows_are_not_counted(self):
+        import numpy as np
+
+        from repro.backends.jax.engine import wave_counts
+
+        # 5 rows on 4 shards of 2: [3, 5] [2, 7] [4, p] [p, p]; the
+        # phantom rows replicate row 4 but could step any number
+        steps = np.array([3, 5, 2, 7, 4, 99, 99, 99])
+        assert wave_counts(steps, 5, 4) == (5 + 7 + 4, 21,
+                                            2 * 5 + 2 * 7 + 1 * 4)
+        assert wave_counts(steps[:5], 5, 1) == (7, 21, 5 * 7)
+
+    def test_rows_that_divide_the_shards(self):
+        import numpy as np
+
+        from repro.backends.jax.engine import wave_counts
+
+        steps = np.array([1, 4, 6, 2])
+        assert wave_counts(steps, 4, 2) == (4 + 6, 13, 2 * 4 + 2 * 6)
+        assert wave_counts(steps, 4, 4) == (13, 13, 13)
+
+
 @multi_device
 class TestShardedParity:
     def test_mesh_really_has_four_devices(self):
@@ -190,6 +215,30 @@ class TestShardedParity:
         for a, b in zip(sharded, single):
             assert a.makespan == b.makespan
             assert a.energy_j == b.energy_j
+
+    def test_sharded_waves_leave_out_phantom_rows(self):
+        """5 rows on 4 devices: the row-waves match one device's, and
+        the phantom rows of the last shard add no waves."""
+        from repro.backends.jax import JaxBatchSimulator
+
+        g = listing2_graph()
+        specs = homogeneous_cluster(3)
+        bounds = [2.5, 6.0, 7.5, 9.0, 12.0]
+        profs = []
+        for devices in (4, 1):
+            sim = JaxBatchSimulator(g, specs, bounds, policy="oracle",
+                                    shard_devices=devices)
+            pending = sim.dispatch()
+            sim.fetch(pending)
+            profs.append(pending.profile)
+        sharded, single = profs
+        assert sharded.devices == 4 and single.devices == 1
+        assert sharded.row_waves == single.row_waves
+        assert single.row_slots == 5 * single.waves
+        assert sharded.row_waves <= sharded.row_slots
+        # three shards hold real rows; their waves are at most 3x the
+        # slowest row's, never 4x
+        assert sharded.waves <= 3 * single.waves
 
     def test_profile_reports_shard_and_phase_split(self):
         grid = family_grid()
