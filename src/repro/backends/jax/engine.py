@@ -17,6 +17,14 @@ and ``jax.vmap``-ed over the row axis.  Two batch layouts share it:
   phantom lanes with zero idle draw; see
   :class:`repro.core.batchsim.BatchArrays`).
 
+Dependencies reach the device as lane-progress thresholds, not as
+lists: ``ctx.need[k, m]`` (built once per batch on the host by
+:func:`readiness_table`) is how many jobs lane ``m`` must have
+completed before job slot ``k`` may start.  A lane completes its jobs
+in ``node_seq`` order and ``ptr`` counts them, so readiness is one row
+gather, one compare and one reduction, ``ptr >= need[cur]``, with no
+per-dependency gather into the ``completed`` flags.
+
 Per wave, the hot path — LUT power->frequency gather, per-node rate
 computation, earliest-event reduction, and (for redistribution policies)
 idle-power reclamation/water-fill — is one call into
@@ -111,7 +119,7 @@ class _Ctx(NamedTuple):
 
     tab: StepTables
     node_seq: jnp.ndarray    # (N, K) int32
-    deps_pad: jnp.ndarray    # (J+1, D) int32
+    need: jnp.ndarray        # (J+1, N) int32 lane-progress thresholds
     work_pad: jnp.ndarray    # (J+1,)
     rho_pad: jnp.ndarray     # (J+1,)
     completed0: jnp.ndarray  # (J+1,) bool start state (phantoms born done)
@@ -122,7 +130,7 @@ class _Ctx(NamedTuple):
 #: vmap ``in_axes`` for a stacked (per-row geometry) batch.
 _CTX_ROW_AXES = _Ctx(
     tab=StepTables(*([0] * len(StepTables._fields))),
-    node_seq=0, deps_pad=0, work_pad=0, rho_pad=0, completed0=0,
+    node_seq=0, need=0, work_pad=0, rho_pad=0, completed0=0,
     n_active=0, dt=None)
 
 
@@ -157,7 +165,9 @@ def _cur(ctx: _Ctx, st: _RowState) -> jnp.ndarray:
 def _ready_mask(ctx: _Ctx, st: _RowState) -> jnp.ndarray:
     j = ctx.work_pad.shape[0] - 1
     cur = _cur(ctx, st)
-    deps_ok = st.completed[ctx.deps_pad[cur]].all(axis=-1)
+    # lane progress against thresholds (see readiness_table): no
+    # per-dependency gather into completed
+    deps_ok = (st.ptr[None, :] >= ctx.need[cur]).all(axis=-1)
     return (~st.running) & (cur < j) & deps_ok & ~st.done
 
 
@@ -351,7 +361,7 @@ def _ctx_specs(stacked: bool) -> _Ctx:
     rows, rep = P("rows"), P()
     leaf = rows if stacked else rep
     return _Ctx(tab=StepTables(*([leaf] * len(StepTables._fields))),
-                node_seq=leaf, deps_pad=leaf, work_pad=leaf,
+                node_seq=leaf, need=leaf, work_pad=leaf,
                 rho_pad=leaf, completed0=leaf, n_active=leaf, dt=rep)
 
 
@@ -453,6 +463,49 @@ def _to_device(x):
     if a.dtype.kind == "i":
         return a.astype(np.int32, copy=False)
     return a
+
+
+def readiness_table(node_seq: np.ndarray,
+                    deps_pad: np.ndarray) -> np.ndarray:
+    """Per-job lane-progress thresholds: the stepper's dependency test.
+
+    A lane completes its jobs in ``node_seq`` order and its pointer
+    ``ptr[m]`` counts the ones done, so job ``X`` at position ``p`` of
+    lane ``m`` is complete exactly when ``ptr[m] > p``.  Entry
+    ``need[k, m]`` is therefore 1 + the highest lane-``m`` position
+    among job ``k``'s dependencies (0 where it has none there), and
+    job ``k`` may start once ``ptr >= need[k]`` on every lane.
+
+    Takes the shared ``(N, K)``/``(J+1, D)`` or the stacked
+    ``(B, N, K)``/``(B, J+1, D)`` arrays, with ``J`` the sentinel slot,
+    and returns ``(J+1, N)`` or ``(B, J+1, N)`` int32.  Sentinel and
+    padding dependencies contribute 0, as do phantom job slots.
+    """
+    stacked = node_seq.ndim == 3
+    if not stacked:
+        node_seq, deps_pad = node_seq[None], deps_pad[None]
+    b, n, _ = node_seq.shape
+    j1, d = deps_pad.shape[1:]
+    # every real job slot's lane and 1-based position, flat over
+    # (row, slot); the sentinel and phantom slots keep lane 0, position
+    # 0, so a dependency on them requires nothing
+    lane = np.zeros(b * j1, np.int64)
+    pos1 = np.zeros(b * j1, np.int32)
+    r, m, p = np.nonzero(node_seq < j1 - 1)
+    slot = r * j1 + node_seq[r, m, p]
+    lane[slot] = m
+    pos1[slot] = p + 1
+    deps = (deps_pad + (np.arange(b) * j1)[:, None, None]).reshape(-1, d)
+    base = np.arange(b * j1) * n      # flat offset of each (row, job)
+    need = np.zeros(b * j1 * n, np.int32)
+    # one column at a time: within a column each (row, job) appears
+    # once, so each (row, job, lane) target is written once
+    for c in range(d):
+        dep = deps[:, c]
+        tgt = base + lane[dep]
+        need[tgt] = np.maximum(need[tgt], pos1[dep])
+    need = need.reshape(b, j1, n)
+    return need if stacked else need[0]
 
 
 class _Pending(NamedTuple):
@@ -560,6 +613,8 @@ class JaxBatchSimulator:
         self.n_jobs_row = n_jobs_row
         self.n_active = n_active
         self.n_jobs_total = arrays.n_jobs
+        # built once here, under the sweep's build region, not per pack
+        self.need = readiness_table(arrays.node_seq, arrays.deps_pad)
 
     def _setup_run_params(self, bounds, policy, dt, latency_s, trace_every,
                           max_steps, use_kernel, kernel_interpret,
@@ -617,7 +672,7 @@ class JaxBatchSimulator:
             n_active = np.asarray(a.n_nodes, np.int32)
         return _Ctx(tab=step_tables(a.table, ftype),
                     node_seq=np.asarray(a.node_seq, np.int32),
-                    deps_pad=np.asarray(a.deps_pad, np.int32),
+                    need=self.need,
                     work_pad=np.asarray(a.work_pad, ftype),
                     rho_pad=np.asarray(a.rho_pad, ftype),
                     completed0=completed0, n_active=n_active,
@@ -646,7 +701,7 @@ class JaxBatchSimulator:
                 ctx = ctx._replace(
                     tab=StepTables(*_pad_rows(pad, *ctx.tab)),
                     node_seq=_pad_rows(pad, ctx.node_seq)[0],
-                    deps_pad=_pad_rows(pad, ctx.deps_pad)[0],
+                    need=_pad_rows(pad, ctx.need)[0],
                     work_pad=_pad_rows(pad, ctx.work_pad)[0],
                     rho_pad=_pad_rows(pad, ctx.rho_pad)[0],
                     completed0=_pad_rows(pad, ctx.completed0)[0],

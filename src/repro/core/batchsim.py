@@ -332,7 +332,9 @@ def estimate_row_bytes(pad_dims: Tuple[int, int, int, int, int],
     backend runs at (4 for the jax engine's float32/int32 default, 8
     for the numpy backend's float64).  The model sums the per-row
     geometry (:class:`BatchArrays` leaves plus the ``(S, N)``/``(1, N)``
-    LUT step tables) and the wave-loop carry
+    LUT step tables; the dependency table counts the wider of the numpy
+    backend's ``(J+1, D)`` lists and the jax engine's ``(J+1, N)``
+    readiness thresholds) and the wave-loop carry
     (lane state, job bookkeeping, start/end stamps) scaled by a
     double-buffering factor.  It is intentionally a slight
     over-estimate: the sweep engine's memory-aware planner uses it to
@@ -345,7 +347,7 @@ def estimate_row_bytes(pad_dims: Tuple[int, int, int, int, int],
     geometry = (
         2 * jp            # work_pad, rho_pad
         + n * k           # node_seq
-        + jp * d          # deps_pad
+        + jp * max(n, d)  # deps_pad, or the jax engine's (J+1, N) need
         + jp              # completed0
         + 2 * s * n       # state_p / state_f step tables
         + 7 * n           # lane vectors (idle/f_min/f_nom/span/...)

@@ -339,3 +339,159 @@ class TestKernelEngineParity:
             for a, b in zip(ref, ker):
                 assert b.makespan == pytest.approx(a.makespan, rel=1e-6)
                 assert b.energy_j == pytest.approx(a.energy_j, rel=1e-6)
+
+
+def _two_lane_graph():
+    """Same-lane and cross-lane single dependencies, and one job with
+    two dependencies on one lane, listed later job first (its threshold
+    is the later job's)."""
+    from repro.core import JobDependencyGraph
+
+    g = JobDependencyGraph()
+    g.add(0, 0, 3.0)
+    g.add(0, 1, 2.0, deps=[(0, 0)])              # same lane
+    g.add(1, 0, 4.0, deps=[(0, 0)])              # cross lane
+    g.add(1, 1, 1.0, deps=[(1, 0), (0, 1)])
+    g.add(2, 0, 2.0)
+    g.add(2, 1, 5.0, deps=[(1, 1), (0, 1), (0, 0)])
+    g.add(0, 2, 2.0, deps=[(2, 1)])
+    return g
+
+
+def _readiness_batch(case):
+    """A jax batch for each readiness case: an IS-like collective graph
+    at 32 ranks, a graph of single dependencies, and a stacked batch
+    padded past its rows (phantom lanes and phantom job slots)."""
+    from repro.core.workloads import is_like, layered_dag
+
+    if case == "is-collective":
+        return JaxBatchSimulator(is_like(32, seed=3),
+                                 homogeneous_cluster(32), [40.0, 90.0])
+    if case == "single-deps":
+        return JaxBatchSimulator(_two_lane_graph(), homogeneous_cluster(3),
+                                 [6.0])
+    items = [(is_like(16, seed=5), homogeneous_cluster(16)),
+             (_two_lane_graph(), homogeneous_cluster(3)),
+             (layered_dag(6, layers=5, fan=3), homogeneous_cluster(6))]
+    return JaxBatchSimulator.padded(items, [30.0, 6.0, 12.0],
+                                    pad_dims=(32, 512, 32, 32, 8))
+
+
+def _plain_need(graph, n_lanes, n_slots):
+    """``need`` for one row, job by job, from the graph itself."""
+    import numpy as np
+
+    job_ids = sorted(graph.jobs)
+    lane = {nid: i for i, nid in enumerate(graph.nodes)}
+    pos = {job.job_id: p for nid in graph.nodes
+           for p, job in enumerate(graph.node_jobs(nid))}
+    need = np.zeros((n_slots, n_lanes), np.int32)
+    for k, jid in enumerate(job_ids):
+        for dep in graph.jobs[jid].deps:
+            m = lane[dep[0]]
+            need[k, m] = max(need[k, m], pos[dep] + 1)
+    return need
+
+
+class TestReadiness:
+    """The stepper decides readiness from lane progress (``ptr`` against
+    the ``need`` table) instead of gathering every dependency from
+    ``completed``; the two must agree on every reachable state."""
+
+    @pytest.mark.parametrize("case", ["is-collective", "single-deps",
+                                      "stacked-padded"])
+    def test_mask_matches_completed_gather(self, case):
+        import jax.numpy as jnp
+        import numpy as np
+
+        from repro.backends.jax import engine
+
+        sim = _readiness_batch(case)
+        ctx = jax.tree_util.tree_map(jnp.asarray, sim._ctx())
+        rng = np.random.default_rng(14)
+        n_states = 200
+        rows = range(sim.n_rows) if sim.stacked else [None]
+        ready_seen = blocked_seen = 0
+        for r in rows:
+            if r is None:
+                ctx_r, deps_pad = ctx, sim.arrays.deps_pad
+            else:      # one row of the stacked geometry (dt is shared)
+                ctx_r = jax.tree_util.tree_map(
+                    lambda x: x[r], ctx._replace(dt=None))._replace(
+                        dt=ctx.dt)
+                deps_pad = sim.arrays.deps_pad[r]
+            node_seq = np.asarray(ctx_r.node_seq)
+            j = len(ctx_r.work_pad) - 1
+            n = node_seq.shape[0]
+            lane_len = (node_seq < j).sum(axis=1)
+            # states the stepper can reach: lane m has completed
+            # exactly its first ptr[m] jobs
+            ptr = np.floor(rng.random((n_states, n))
+                           * (lane_len + 1)).astype(np.int32)
+            completed = np.repeat(np.asarray(ctx_r.completed0)[None],
+                                  n_states, axis=0)
+            for s in range(n_states):
+                for m in range(n):
+                    completed[s, node_seq[m, :ptr[s, m]]] = True
+            running = rng.random((n_states, n)) < 0.2
+            cur = node_seq[np.arange(n)[None, :], ptr]
+            want = (~running & (cur < j)
+                    & completed[np.arange(n_states)[:, None, None],
+                                deps_pad[cur]].all(axis=-1))
+
+            def mask(p, c, run):
+                st = engine._RowState(
+                    ptr=p, running=run, remaining=jnp.ones(n),
+                    completed=c, row_t=0.0, bound=1.0, sched_idx=0,
+                    done=jnp.zeros((), bool), stalled=False, energy=0.0,
+                    peak=0.0, over_t=0.0, makespan=0.0, start_t=None,
+                    end_t=None, tick_count=0, steps=0)
+                return engine._ready_mask(ctx_r, st)
+
+            got = np.asarray(jax.vmap(mask)(ptr, completed, running))
+            np.testing.assert_array_equal(got, want)
+            ready_seen += int(want.sum())
+            blocked_seen += int((~want & ~running & (cur < j)).sum())
+        # the states exercise both answers
+        assert ready_seen > 0 and blocked_seen > 0
+
+    @pytest.mark.parametrize("layout", ["shared", "stacked"])
+    def test_need_table_matches_plain_build(self, layout):
+        import numpy as np
+
+        if layout == "shared":
+            sim = _readiness_batch("is-collective")
+            g = sim.graph
+            assert sim.need.shape == (sim.n_jobs_total + 1, sim.n_nodes)
+            np.testing.assert_array_equal(
+                sim.need, _plain_need(g, sim.n_nodes, sim.n_jobs_total + 1))
+            return
+        sim = _readiness_batch("stacked-padded")
+        n, j1 = sim.n_nodes, sim.n_jobs_total + 1
+        assert sim.need.shape == (sim.n_rows, j1, n)
+        assert sim.need.dtype == np.int32
+        for r, g in enumerate(sim.row_graphs):
+            want = _plain_need(g, n, j1)
+            # real slots keep their ids; phantom slots and the sentinel
+            # need nothing, and no job waits on a phantom lane
+            np.testing.assert_array_equal(sim.need[r], want)
+            assert not sim.need[r, :, int(sim.n_active[r]):].any()
+
+    @pytest.mark.parametrize("policy", ["equal-share", "oracle"])
+    def test_stamps_match_numpy_backend(self, policy):
+        """Job start and end times on a collective graph agree with the
+        numpy batch backend's (float32 against float64)."""
+        from repro.core.workloads import is_like
+
+        g, specs = is_like(8, seed=9), homogeneous_cluster(8)
+        bounds = [12.0, 30.0]
+        jx = simulate_batch_jax(g, specs, bounds, policy)
+        vec = simulate_batch(g, specs, bounds, policy)
+        for a, b in zip(jx, vec):
+            assert a.job_starts.keys() == b.job_starts.keys() == g.jobs.keys()
+            assert a.job_ends.keys() == b.job_ends.keys()
+            for jid in g.jobs:
+                assert a.job_starts[jid] == pytest.approx(
+                    b.job_starts[jid], rel=1e-5, abs=1e-5), jid
+                assert a.job_ends[jid] == pytest.approx(
+                    b.job_ends[jid], rel=1e-5, abs=1e-5), jid

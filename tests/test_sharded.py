@@ -65,6 +65,30 @@ class TestPlanner:
         assert estimate_row_bytes((4, 16, 4, 2, 4), itemsize=8) \
             == 2 * small
 
+    def test_row_bytes_covers_wide_clusters(self):
+        """With more lanes than dependencies (N > D) the jax engine's
+        (J+1, N) readiness table outgrows the (J+1, D) lists; the
+        estimate counts the wider of the two, so it still over-estimates
+        a real padded row."""
+        import numpy as np
+
+        from repro.backends.jax import JaxBatchSimulator
+        from repro.core import layered_dag
+
+        dims = (16, 63, 8, 4, 8)
+        assert estimate_row_bytes(dims) == estimate_row_bytes(
+            (16, 63, 8, 16, 8))
+        assert estimate_row_bytes((16, 63, 8, 32, 8)) \
+            > estimate_row_bytes(dims)
+        g = layered_dag(12, layers=4, fan=2)
+        sim = JaxBatchSimulator.padded([(g, homogeneous_cluster(12))],
+                                       [20.0], pad_dims=dims)
+        ctx = sim._ctx()
+        geometry = sum(np.asarray(leaf).nbytes
+                       for leaf in jax.tree_util.tree_leaves(ctx))
+        assert sim.need.shape == (1, 64, 16)
+        assert geometry < estimate_row_bytes(dims)
+
     def test_chunk_rows_aligned_and_floored(self):
         # budget of 10 rows, 4-way alignment -> 8 rows per chunk
         assert plan_chunk_rows(100, 1000, align=4) == 8
@@ -239,6 +263,18 @@ class TestShardedParity:
         # three shards hold real rows; their waves are at most 3x the
         # slowest row's, never 4x
         assert sharded.waves <= 3 * single.waves
+
+    def test_sharded_stamps_match_single_device(self):
+        """Every job's start and end time, through shared and padded
+        buckets, is the same on four devices as on one."""
+        grid = family_grid(("equal-share", "oracle"))
+        s4 = SweepEngine(executor="jax").run(grid)
+        s1 = SweepEngine(executor="jax", shard_devices=1).run(grid)
+        assert not s4.failures and not s1.failures
+        assert {b.devices for b in s4.profile.buckets} >= {4}
+        for a, b in zip(s4.records, s1.records):
+            assert a.result.job_starts == b.result.job_starts
+            assert a.result.job_ends == b.result.job_ends
 
     def test_profile_reports_shard_and_phase_split(self):
         grid = family_grid()
