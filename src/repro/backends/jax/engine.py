@@ -4,7 +4,7 @@ This is :class:`~repro.core.batchsim.BatchSimulator`'s state machine —
 wave time advancement with completions, dependency hand-offs, energy
 accounting and policy caps resolved at exact event times — ported to a
 compiled ``jax.lax.while_loop`` stepper.  The stepper is written for a
-*single* scenario row (``(N,)`` lane state, ``(J+1,)`` job bookkeeping)
+*single* scenario row (``(N,)`` lane state, ``(N, K)`` job stamps)
 and ``jax.vmap``-ed over the row axis.  Two batch layouts share it:
 
 * **shared** (the constructor): one graph and cluster, B bounds — the
@@ -23,7 +23,16 @@ lists: ``ctx.need[k, m]`` (built once per batch on the host by
 completed before job slot ``k`` may start.  A lane completes its jobs
 in ``node_seq`` order and ``ptr`` counts them, so readiness is one row
 gather, one compare and one reduction, ``ptr >= need[cur]``, with no
-per-dependency gather into the ``completed`` flags.
+per-dependency gather into the ``completed`` flags.  The same holds
+for the job stamps: every start and completion of a wave happens at the
+row's one instant, so the loop carries only lane state, and each wave
+ends by stamping the ``(N, K)`` lane positions its starts and
+completions passed (one masked write); the ``(J+1,)`` stamps and
+``completed`` flags are scattered from them once, when the row ends.
+``_settle``'s rounds therefore touch nothing ``J``-sized, and a loop
+iteration unrolls :data:`SETTLE_UNROLL` of them: a wave whose cascade
+runs through send/recv markers (2 to 4 rounds) settles in one
+iteration, and a round past the fixed point changes nothing.
 
 Per wave, the hot path — LUT power->frequency gather, per-node rate
 computation, earliest-event reduction, and (for redistribution policies)
@@ -75,7 +84,7 @@ one sync per field.  ``run()`` is ``fetch(dispatch())``.
 from __future__ import annotations
 
 import functools
-import math
+import itertools
 import threading
 import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple, Union
@@ -85,7 +94,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.batchsim import (BatchArrays, GraphArrays,
+from repro.core.batchsim import (BatchArrays, GraphArrays, _frozen,
                                  build_graph_arrays, pad_bound_schedules,
                                  stack_graph_arrays, validate_padded_items)
 from repro.core.graph import JobDependencyGraph
@@ -140,7 +149,6 @@ class _RowState(NamedTuple):
     ptr: jnp.ndarray        # (N,) int32 current-job pointer
     running: jnp.ndarray    # (N,) bool
     remaining: jnp.ndarray  # (N,)
-    completed: jnp.ndarray  # (J+1,) bool, sentinel slot always True
     row_t: jnp.ndarray      # scalar
     bound: jnp.ndarray      # scalar *current* bound (schedules update it)
     sched_idx: jnp.ndarray  # scalar int32: next bound-schedule entry
@@ -150,10 +158,11 @@ class _RowState(NamedTuple):
     peak: jnp.ndarray       # scalar
     over_t: jnp.ndarray     # scalar
     makespan: jnp.ndarray   # scalar
-    start_t: jnp.ndarray    # (J+1,), NaN until started, sentinel junk
-    end_t: jnp.ndarray      # (J+1,), NaN until completed, sentinel junk
+    start_at: jnp.ndarray   # (N, K) start of each lane position, NaN until
+    end_at: jnp.ndarray     # (N, K) end of each lane position, NaN until
     tick_count: jnp.ndarray  # scalar int32
     steps: jnp.ndarray      # scalar int32
+    settle_rounds: jnp.ndarray  # scalar int32: _settle's rounds
 
 
 def _cur(ctx: _Ctx, st: _RowState) -> jnp.ndarray:
@@ -176,45 +185,85 @@ def _instant_mask(st: _RowState) -> jnp.ndarray:
 
 
 def _start(ctx: _Ctx, st: _RowState, mask: jnp.ndarray) -> _RowState:
-    j = ctx.work_pad.shape[0] - 1
-    cur = _cur(ctx, st)
-    tgt = jnp.where(mask, cur, j)       # masked-off lanes hit the junk slot
     return st._replace(
         running=st.running | mask,
-        remaining=jnp.where(mask, ctx.work_pad[cur], st.remaining),
-        start_t=st.start_t.at[tgt].set(st.row_t))
+        remaining=jnp.where(mask, ctx.work_pad[_cur(ctx, st)],
+                            st.remaining))
 
 
-def _complete(ctx: _Ctx, st: _RowState, mask: jnp.ndarray) -> _RowState:
-    j = ctx.work_pad.shape[0] - 1
-    cur = _cur(ctx, st)
-    tgt = jnp.where(mask, cur, j)
-    completed = st.completed.at[tgt].set(True)   # sentinel stays True
-    all_done = completed[:j].all()
-    newly = ~st.done & all_done
-    return st._replace(
-        completed=completed,
-        end_t=st.end_t.at[tgt].set(st.row_t),
-        ptr=st.ptr + mask.astype(st.ptr.dtype),
-        running=st.running & ~mask,
-        makespan=jnp.where(newly, st.row_t, st.makespan),
-        done=st.done | all_done)
+def _complete(st: _RowState, mask: jnp.ndarray) -> _RowState:
+    return st._replace(ptr=st.ptr + mask.astype(st.ptr.dtype),
+                       running=st.running & ~mask)
+
+
+def _started(st: _RowState) -> jnp.ndarray:
+    """Jobs each lane has started: the completed ones and the running."""
+    return st.ptr + st.running.astype(st.ptr.dtype)
+
+
+#: Settle rounds one loop iteration runs.  The graph's work plays no
+#: part, so a graph with its work zeroed (a warm-up) compiles the
+#: stepper its real graph runs.
+SETTLE_UNROLL = 4
 
 
 def _settle(ctx: _Ctx, st: _RowState) -> _RowState:
     """Fixed point of everything that happens at the row's instant:
     start ready jobs, complete zero-work jobs, repeat until stable
     (mirrors ``BatchSimulator._settle``; policy caps are re-derived at
-    the top of the next wave instead of via hooks)."""
+    the top of the next wave instead of via hooks).  A round evaluates
+    the ready mask once, for its successor; its carry is lane state
+    only.  Each loop iteration runs :data:`SETTLE_UNROLL` rounds, and a
+    round after the fixed point changes nothing, so only the rounds
+    that start or complete a job are counted in ``settle_rounds``."""
 
-    def cond(s):
-        return _ready_mask(ctx, s).any() | _instant_mask(s).any()
+    def go(s, ready):
+        return ready.any() | _instant_mask(s).any()
 
-    def body(s):
-        s = _start(ctx, s, _ready_mask(ctx, s))
-        return _complete(ctx, s, _instant_mask(s))
+    def body(carry):
+        s, ready, live = carry
+        for _ in range(SETTLE_UNROLL):
+            s = _start(ctx, s, ready)
+            s = _complete(s, _instant_mask(s))
+            s = s._replace(settle_rounds=s.settle_rounds
+                           + live.astype(jnp.int32))
+            ready = _ready_mask(ctx, s)
+            live = go(s, ready)
+        return s, ready, live
 
-    return jax.lax.while_loop(cond, body, st)
+    ready = _ready_mask(ctx, st)
+    st, _, _ = jax.lax.while_loop(lambda c: c[2], body,
+                                  (st, ready, go(st, ready)))
+    return st
+
+
+def _stamp(ctx: _Ctx, st: _RowState, ptr0: jnp.ndarray,
+           started0: jnp.ndarray) -> _RowState:
+    """Close the row's instant: stamp ``row_t`` on the lane positions
+    completed (from ``ptr0``) and started (from ``started0``) since the
+    instant began, and mark the row done once every lane is exhausted
+    (its current slot the sentinel)."""
+    j = ctx.work_pad.shape[0] - 1
+    pos = jnp.arange(ctx.node_seq.shape[-1])[None, :]
+
+    def passed(lo, hi):
+        return (pos >= lo[:, None]) & (pos < hi[:, None])
+
+    all_done = (_cur(ctx, st) == j).all()
+    newly = ~st.done & all_done
+    return st._replace(
+        start_at=jnp.where(passed(started0, _started(st)), st.row_t,
+                           st.start_at),
+        end_at=jnp.where(passed(ptr0, st.ptr), st.row_t, st.end_at),
+        makespan=jnp.where(newly, st.row_t, st.makespan),
+        done=st.done | all_done)
+
+
+def _by_job(ctx: _Ctx, at: jnp.ndarray, fill) -> jnp.ndarray:
+    """A ``(N, K)`` lane-position array laid out by job slot ``(J+1,)``;
+    padding positions all land in the sentinel slot, which is junk."""
+    base = jnp.full(ctx.work_pad.shape[0], fill, at.dtype)
+    return base.at[ctx.node_seq].set(at)
 
 
 def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
@@ -228,15 +277,15 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
     st0 = _RowState(
         ptr=jnp.zeros(n, jnp.int32), running=jnp.zeros(n, bool),
         remaining=jnp.zeros(n, ftype),
-        completed=ctx.completed0,
         row_t=zero, bound=jnp.asarray(bound, ftype),
         sched_idx=jnp.zeros((), jnp.int32),
         done=jnp.zeros((), bool), stalled=jnp.zeros((), bool),
         energy=zero, peak=zero, over_t=zero, makespan=zero,
-        start_t=jnp.full(ctx.work_pad.shape[0], jnp.nan, ftype),
-        end_t=jnp.full(ctx.work_pad.shape[0], jnp.nan, ftype),
-        tick_count=jnp.zeros((), jnp.int32), steps=jnp.zeros((), jnp.int32))
-    st0 = _settle(ctx, st0)
+        start_at=jnp.full(ctx.node_seq.shape, jnp.nan, ftype),
+        end_at=jnp.full(ctx.node_seq.shape, jnp.nan, ftype),
+        tick_count=jnp.zeros((), jnp.int32), steps=jnp.zeros((), jnp.int32),
+        settle_rounds=jnp.zeros((), jnp.int32))
+    st0 = _stamp(ctx, _settle(ctx, st0), st0.ptr, _started(st0))
 
     def cond(carry):
         st, _ = carry
@@ -244,6 +293,7 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
 
     def body(carry):
         st, pol = carry
+        ptr0, started0 = st.ptr, _started(st)
         caps = cls.caps_fn(ctx, st, pol)
         rate2, _, t_fin2, _, p_cl2, t_comp2 = power_step(
             ctx.tab, caps[None, :].astype(ftype),
@@ -296,20 +346,24 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
             over_t=st.over_t + jnp.where(over, delta, 0.0),
             stalled=st.stalled | stalled_now,
             steps=st.steps + 1)
-        st = _complete(ctx, st, finishing)
+        st = _complete(st, finishing)
         if wants_ticks:
             pol = cls.tick_fn(ctx, st, pol, due)
             st = st._replace(
                 tick_count=st.tick_count + due.astype(jnp.int32))
-        st = _settle(ctx, st)
+        st = _stamp(ctx, _settle(ctx, st), ptr0, started0)
         return st, pol
 
     st, _ = jax.lax.while_loop(cond, body, (st0, pol_state))
+    done_at = jnp.arange(ctx.node_seq.shape[-1])[None, :] < st.ptr[:, None]
     return {
         "makespan": st.makespan, "energy": st.energy, "peak": st.peak,
-        "over_t": st.over_t, "start_t": st.start_t, "end_t": st.end_t,
-        "completed": st.completed, "done": st.done, "stalled": st.stalled,
-        "steps": st.steps,
+        "over_t": st.over_t,
+        "start_t": _by_job(ctx, st.start_at, jnp.nan),
+        "end_t": _by_job(ctx, st.end_at, jnp.nan),
+        "completed": ctx.completed0 | _by_job(ctx, done_at, False),
+        "done": st.done, "stalled": st.stalled,
+        "steps": st.steps, "settle_rounds": st.settle_rounds,
     }
 
 
@@ -563,7 +617,9 @@ class JaxBatchSimulator:
             row_graphs=[graph] * b, row_specs=[self.specs] * b,
             row_job_ids=(tuple(arrays.job_ids),) * b,
             n_jobs_row=np.full(b, arrays.n_jobs),
-            n_active=np.full(b, arrays.n_nodes))
+            n_active=np.full(b, arrays.n_nodes),
+            need=graph.derived("jax.need", lambda g: _frozen(
+                readiness_table(arrays.node_seq, arrays.deps_pad))))
 
     @classmethod
     def padded(cls, items: Sequence[Tuple[JobDependencyGraph,
@@ -601,10 +657,12 @@ class JaxBatchSimulator:
 
     # ------------------------------------------------------- construction
     def _init_rows(self, arrays, *, stacked, row_graphs, row_specs,
-                   row_job_ids, n_jobs_row, n_active) -> None:
+                   row_job_ids, n_jobs_row, n_active, need=None) -> None:
         """One home for the per-row bookkeeping both layouts must fill
         (mirrors ``BatchSimulator._init_geometry`` — policies rely on
-        these attributes being layout-agnostic)."""
+        these attributes being layout-agnostic).  ``need`` is the
+        readiness table when the caller kept one (the shared layout
+        keeps it with its graph), else it is built here."""
         self.arrays = arrays
         self.stacked = stacked
         self.row_graphs = row_graphs
@@ -613,8 +671,9 @@ class JaxBatchSimulator:
         self.n_jobs_row = n_jobs_row
         self.n_active = n_active
         self.n_jobs_total = arrays.n_jobs
-        # built once here, under the sweep's build region, not per pack
-        self.need = readiness_table(arrays.node_seq, arrays.deps_pad)
+        # built here, under the sweep's build region, not per pack
+        self.need = need if need is not None else readiness_table(
+            arrays.node_seq, arrays.deps_pad)
 
     def _setup_run_params(self, bounds, policy, dt, latency_s, trace_every,
                           max_steps, use_kernel, kernel_interpret,
@@ -781,7 +840,8 @@ class JaxBatchSimulator:
         The whole output pytree comes back in ONE fused device-to-host
         transfer (``jax.device_get``) — never one sync per field — and
         shard-padding phantom rows are trimmed before any bookkeeping.
-        The profile counts the batch's waves from the rows' ``steps``.
+        The profile counts the batch's waves from the rows' ``steps``
+        and their settle rounds from ``settle_rounds``.
         """
         prof = pending.profile
         args = {"bucket": prof.bucket, "rows": self.n_rows,
@@ -797,9 +857,12 @@ class JaxBatchSimulator:
             prof.transfer_s = time.perf_counter() - t1
         prof.waves, prof.row_waves, prof.row_slots = wave_counts(
             np.asarray(out["steps"]), self.n_rows, self.n_shards)
+        prof.settle_rounds = int(
+            np.asarray(out["settle_rounds"])[:self.n_rows].sum())
         with obs_trace.region("results", "engine", waves=prof.waves,
                               row_waves=prof.row_waves,
-                              row_slots=prof.row_slots, **args):
+                              row_slots=prof.row_slots,
+                              settle_rounds=prof.settle_rounds, **args):
             out = {k: np.asarray(v)[:self.n_rows] for k, v in out.items()}
             self._check_failures(out)
             return self._results(out)
@@ -824,15 +887,19 @@ class JaxBatchSimulator:
     def _results(self, out: Dict[str, np.ndarray]) -> List[SimResult]:
         name = self.policy.name
         results: List[SimResult] = []
+
+        def stamps(job_ids, t):
+            """Job id -> stamp, jobs never stamped (NaN) left out."""
+            t = t[:len(job_ids)]
+            seen = ~np.isnan(t)
+            return dict(zip(itertools.compress(job_ids, seen),
+                            t[seen].tolist()))
+
         for row in range(self.n_rows):
             job_ids = self.row_job_ids[row]
             makespan = float(out["makespan"][row])
-            starts = {jid: float(out["start_t"][row, k])
-                      for k, jid in enumerate(job_ids)
-                      if not math.isnan(out["start_t"][row, k])}
-            ends = {jid: float(out["end_t"][row, k])
-                    for k, jid in enumerate(job_ids)
-                    if not math.isnan(out["end_t"][row, k])}
+            starts = stamps(job_ids, out["start_t"][row])
+            ends = stamps(job_ids, out["end_t"][row])
             energy = float(out["energy"][row])
             results.append(SimResult(
                 policy=name, makespan=makespan, energy_j=energy,

@@ -21,7 +21,9 @@ with the four phases of its life separated out:
 
 and its **waves** — the lockstep while-loop iterations the device ran
 and the rows' own waves, counted at fetch from the ``steps`` the
-stepper already returns (device time over waves is the cost of one).
+stepper already returns (device time over waves is the cost of one) —
+and its **settle rounds**, the rounds of each wave's fixed point of
+starts and zero-work completions (``_settle``), summed over the rows.
 
 :class:`SweepProfile` aggregates the buckets of one sweep and renders
 the one-line summary that ``SweepResult.backend_summary()`` appends.
@@ -60,6 +62,10 @@ class BucketProfile:
     #: row-waves stepped, a row's own or idle behind the slowest row
     #: (``rows * waves`` on one shard)
     row_slots: int = 0
+    #: rounds of ``_settle``'s fixed point summed over the real rows,
+    #: the settle before the first wave included (÷ ``row_waves``:
+    #: rounds a wave)
+    settle_rounds: int = 0
 
     def to_dict(self) -> Dict[str, object]:
         """Flat JSON-ready payload (BENCH records embed these)."""
@@ -73,6 +79,7 @@ class BucketProfile:
             "transfer_s": self.transfer_s,
             "waves": self.waves, "row_waves": self.row_waves,
             "row_slots": self.row_slots,
+            "settle_rounds": self.settle_rounds,
         }
 
 

@@ -14,8 +14,9 @@ Layers:
                    padded mixed-shape bucketing
   scenarios      — seeded ScenarioFamily generators (mixed shapes,
                    relative bounds, dynamic bound steps)
-  workloads      — Listing-2 example, NPB analogues, random layered /
-                   fork-join generators, pipeline/MoE graphs
+  workloads      — Listing-2 example, NPB analogues (IS, EP, CG, LU),
+                   random layered / fork-join generators, pipeline/MoE
+                   graphs
   hlo_extract    — job graphs from compiled JAX/XLA steps (§VII-A1 analogue)
   roofline       — three-term roofline from dry-run artifacts
 """
@@ -44,7 +45,7 @@ from .workloads import (LISTING2_TIMES, MatchReport, TraceBuilder,
                         cg_builder, cg_like, ep_builder, ep_like,
                         fork_join_graph, is_builder, is_like, layered_dag,
                         listing2_graph, listing2_random, listing2_uniform,
-                        match_comm_ops, moe_step_builder, moe_step_graph,
-                        pipeline_graph)
+                        lu_builder, lu_like, match_comm_ops,
+                        moe_step_builder, moe_step_graph, pipeline_graph)
 
 __all__ = [k for k in dir() if not k.startswith("_")]

@@ -140,9 +140,14 @@ class GraphArrays(NamedTuple):
         return self.node_seq.shape[0]
 
 
-def build_graph_arrays(graph: JobDependencyGraph,
-                       specs: Sequence[NodeSpec]) -> GraphArrays:
-    """Flatten a validated graph + cluster into :class:`GraphArrays`."""
+def _frozen(a: np.ndarray) -> np.ndarray:
+    a.flags.writeable = False
+    return a
+
+
+def _lanes(graph: JobDependencyGraph) -> tuple:
+    """The graph-only part of :class:`GraphArrays`, read-only: ``job_ids``,
+    ``work_pad``, ``rho_pad``, ``node_seq``, ``deps_pad``."""
     node_ids = graph.nodes
     n = len(node_ids)
     job_ids: List[JobId] = sorted(graph.jobs)
@@ -153,19 +158,38 @@ def build_graph_arrays(graph: JobDependencyGraph,
     for k, jid in enumerate(job_ids):
         work_pad[k] = graph.jobs[jid].work
         rho_pad[k] = graph.jobs[jid].cpu_frac
-    seqs = [[k_of[job.job_id] for job in graph.node_jobs(nid)]
-            for nid in node_ids]
-    k_max = max(len(s) for s in seqs)
+    # job ids sort by (node, index), so each lane is one run of slots
+    lane_of = np.fromiter((jid[0] for jid in job_ids), np.int64, j)
+    lane_idx = np.searchsorted(np.asarray(node_ids), lane_of)
+    starts = np.searchsorted(lane_idx, np.arange(n))
+    pos = np.arange(j) - starts[lane_idx]
+    k_max = int(pos.max(initial=-1)) + 1
     node_seq = np.full((n, k_max + 1), j, dtype=np.int64)
-    for i, s in enumerate(seqs):
-        node_seq[i, :len(s)] = s
+    node_seq[lane_idx, pos] = np.arange(j)
     d_max = max((len(graph.jobs[jid].deps) for jid in job_ids),
                 default=0) or 1
     deps_pad = np.full((j + 1, d_max), j, dtype=np.int64)
     for k, jid in enumerate(job_ids):
         deps = [k_of[d] for d in graph.jobs[jid].deps]
         deps_pad[k, :len(deps)] = deps
-    return GraphArrays(job_ids=tuple(job_ids), work_pad=work_pad,
+    return (tuple(job_ids), _frozen(work_pad), _frozen(rho_pad),
+            _frozen(node_seq), _frozen(deps_pad))
+
+
+def lane_arrays(graph: JobDependencyGraph) -> tuple:
+    """``(job_ids, work_pad, rho_pad, node_seq, deps_pad)`` of
+    :class:`GraphArrays`, built once per graph and kept with it
+    (:meth:`JobDependencyGraph.derived`), read-only."""
+    return graph.derived("batchsim.lanes", _lanes)
+
+
+def build_graph_arrays(graph: JobDependencyGraph,
+                       specs: Sequence[NodeSpec]) -> GraphArrays:
+    """Flatten a validated graph + cluster into :class:`GraphArrays`:
+    the graph's part from :func:`lane_arrays`, the cluster's tables
+    built per call."""
+    job_ids, work_pad, rho_pad, node_seq, deps_pad = lane_arrays(graph)
+    return GraphArrays(job_ids=job_ids, work_pad=work_pad,
                        rho_pad=rho_pad, node_seq=node_seq,
                        deps_pad=deps_pad, table=lut_table(specs))
 

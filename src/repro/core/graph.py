@@ -60,9 +60,15 @@ class JobDependencyGraph:
 
     def __init__(self, jobs: Iterable[Job] = ()):
         self._jobs: Dict[JobId, Job] = {}
+        self._derived: Dict[str, object] = {}
         for job in jobs:
             self.add_job(job)
         self._topo_cache: List[JobId] | None = None
+
+    def __getstate__(self):
+        # derived values are rebuilt where the graph lands (a process
+        # pool's worker), not shipped with it
+        return {**self.__dict__, "_derived": {}}
 
     # ------------------------------------------------------------------ build
     def add_job(self, job: Job) -> None:
@@ -74,6 +80,20 @@ class JobDependencyGraph:
             raise GraphError(f"cpu_frac out of [0,1] for {job.job_id}")
         self._jobs[job.job_id] = job
         self._topo_cache = None
+        self._derived.clear()
+
+    def derived(self, key: str, build: Callable[["JobDependencyGraph"],
+                                                object]) -> object:
+        """``build(self)``, computed once and kept until a job is added.
+
+        For values that depend on the graph alone, such as the flat
+        arrays the batch backends build (:mod:`repro.core.batchsim`):
+        a sweep that runs the same graph again skips the rebuild.  The
+        value is shared by every caller, so it must not be mutated.
+        """
+        if key not in self._derived:
+            self._derived[key] = build(self)
+        return self._derived[key]
 
     def add(self, node: int, index: int, work: float, deps=(), cpu_frac=1.0,
             tag: str = "") -> Job:

@@ -54,7 +54,7 @@ from typing import (Callable, Dict, Iterable, List, Mapping, Optional,
 
 from repro.obs import trace as obs_trace
 
-from .batchsim import BatchSimulator, estimate_row_bytes
+from .batchsim import BatchSimulator, estimate_row_bytes, lane_arrays
 from .graph import JobDependencyGraph
 from .ilp import PowerAssignment
 from .power import NodeSpec
@@ -345,31 +345,19 @@ def next_pow2(x: int) -> int:
     return 1 << (max(1, int(x)) - 1).bit_length()
 
 
-def scenario_dims(s: Scenario,
-                  cache: Optional[Dict[tuple, tuple]] = None
-                  ) -> Tuple[int, int, int, int, int]:
+def scenario_dims(s: Scenario) -> Tuple[int, int, int, int, int]:
     """A scenario's batching shape ``(N, J, K, D, S)``: nodes, jobs,
     per-lane sequence length (jobs-per-node max + 1), dependency
-    fan-in, LUT states.  ``cache`` (keyed on the graph/specs
-    identities) skips the O(J + N) graph walk for the many scenarios
-    of a sweep that share one graph."""
-    key = (id(s.graph), id(s.specs))
-    if cache is not None and key in cache:
-        return cache[key]
-    g = s.graph
-    n = len(g.nodes)
-    j = len(g.jobs)
-    k = max(len(g.node_jobs(nid)) for nid in g.nodes) + 1
-    d = max((len(job.deps) for job in g.jobs.values()), default=0) or 1
+    fan-in, LUT states.  The first four are the shapes of the graph's
+    :func:`lane_arrays`, built once per graph and kept with it."""
+    _, _, _, node_seq, deps_pad = lane_arrays(s.graph)
+    n, k = node_seq.shape
+    j, d = deps_pad.shape[0] - 1, deps_pad.shape[1]
     lut_states = max(len(sp.lut.states) for sp in s.specs)
-    dims = (n, j, k, d, lut_states)
-    if cache is not None:
-        cache[key] = dims
-    return dims
+    return (n, j, k, d, lut_states)
 
 
-def bucket_key(backend: str, s: Scenario,
-               dims_cache: Optional[Dict[tuple, tuple]] = None) -> tuple:
+def bucket_key(backend: str, s: Scenario) -> tuple:
     """Scenarios sharing a key run as ONE batch: same backend, policy,
     latency and trace config, and the same power-of-two (N, J) padding
     envelope.  Rounding nodes/jobs up to powers of two keeps the bucket
@@ -378,7 +366,7 @@ def bucket_key(backend: str, s: Scenario,
     the bucket's own power-of-two maxima at build time, so they never
     split buckets but compiled jax steppers are still reused across
     similarly-sized sweeps."""
-    n, j = scenario_dims(s, dims_cache)[:2]
+    n, j = scenario_dims(s)[:2]
     return (backend, s.policy, round(s.latency_s, 12), s.trace_every,
             (next_pow2(n), next_pow2(j)))
 
@@ -697,10 +685,8 @@ class SweepEngine:
     _next_pow2 = staticmethod(next_pow2)
     _scenario_dims = staticmethod(scenario_dims)
 
-    def _bucket_key(self, backend: str, s: Scenario,
-                    dims_cache: Optional[Dict[tuple, tuple]] = None
-                    ) -> tuple:
-        return bucket_key(backend, s, dims_cache)
+    def _bucket_key(self, backend: str, s: Scenario) -> tuple:
+        return bucket_key(backend, s)
 
     def _shared(self, scens: List[Scenario]) -> bool:
         """One graph and one cluster: the zero-padding shared layout."""
@@ -724,12 +710,11 @@ class SweepEngine:
             plans = [self._plan_backend(s, requested) for s in scenarios]
             groups: Dict[tuple, List[int]] = {}
             leftovers: List[int] = []
-            dims_cache: Dict[tuple, tuple] = {}
             for k, s in enumerate(scenarios):
                 backend, _ = plans[k]
                 if backend in self.BATCHED_EXECUTORS:
                     groups.setdefault(
-                        self._bucket_key(backend, s, dims_cache),
+                        self._bucket_key(backend, s),
                         []).append(k)
                 else:
                     leftovers.append(k)
@@ -784,7 +769,7 @@ class SweepEngine:
         for bnum, (key, idxs) in enumerate(groups.items()):
             backend, (n_pad, j_pad) = key[0], key[-1]
             # minor dims: power-of-two of the bucket's own maxima
-            minor = [self._scenario_dims(scenarios[k], dims_cache)[2:]
+            minor = [self._scenario_dims(scenarios[k])[2:]
                      for k in idxs]
             pad_dims = (n_pad, j_pad) + tuple(
                 self._next_pow2(max(col)) for col in zip(*minor))

@@ -418,6 +418,103 @@ def cg_like(n_nodes: int, klass: str = "A", iterations: int = 15,
     return cg_builder(n_nodes, klass, iterations, seed).build()
 
 
+#: Points a side of NPB LU's class B grid in x and y (``isiz01``).
+LU_GRID = 102
+
+
+def lu_proc_grid(n_nodes: int) -> Tuple[int, int]:
+    """NPB LU's ``proc_grid``: ``(xdim, ydim)`` for a power of two ranks."""
+    if n_nodes < 1 or n_nodes & (n_nodes - 1):
+        raise ValueError(f"NPB LU needs a power of two ranks, got {n_nodes}")
+    ndim = n_nodes.bit_length() - 1
+    xdim = 2 ** (ndim // 2) * (2 if ndim % 2 else 1)
+    return xdim, n_nodes // xdim
+
+
+def lu_extent(points: int, parts: int, index: int) -> int:
+    """NPB LU's ``subdomain``: points of part ``index`` when ``points``
+    are split over ``parts``; the first ``points % parts`` get one more."""
+    return points // parts + (1 if index < points % parts else 0)
+
+
+def lu_builder(n_nodes: int, klass: str = "A", iterations: int = 2,
+               nz: int = LU_GRID, seed: int = 6) -> TraceBuilder:
+    """The :func:`lu_like` op script as an unbuilt :class:`TraceBuilder`.
+
+    Rank ``r`` sits at row ``r % xdim`` and column ``r // xdim`` of
+    NPB's process grid (``neighbors.f``): north and south are the rows
+    either side, west and east the columns.  Its block holds ``ni × nj``
+    points of the ``LU_GRID``² plane.  Assumed sizes, at the nominal
+    frequency: a plane of a sweep is ``0.125 × scale × ni·nj / 169`` s
+    times ``U(0.9, 1.1)``, ``cpu_frac`` 0.75; ``rhs`` is ``nz − 2``
+    planes' worth times one such draw, ``cpu_frac`` 0.60 (in NPB LU
+    ``rhs`` costs about as much as one sweep).
+    """
+    scale = NPB_CLASSES[klass]
+    rng = random.Random(seed)
+    xdim, ydim = lu_proc_grid(n_nodes)
+    tb = TraceBuilder(n_nodes)
+    planes = nz - 2
+    plane_w, around = [], []
+    for r in range(n_nodes):
+        row, col = r % xdim, r // xdim
+        ni = lu_extent(LU_GRID, xdim, row)
+        nj = lu_extent(LU_GRID, ydim, col)
+        plane_w.append(0.125 * scale * ni * nj / 169)
+        around.append({"n": r - 1 if row > 0 else None,
+                       "s": r + 1 if row < xdim - 1 else None,
+                       "w": r - xdim if col > 0 else None,
+                       "e": r + xdim if col < ydim - 1 else None})
+
+    def sweep(r, recv_from, send_to):
+        for side in recv_from:
+            if around[r][side] is not None:
+                tb.recv(r, around[r][side])
+        tb.compute(r, plane_w[r] * _skew(rng, 0.1), cpu_frac=0.75)
+        for side in send_to:
+            if around[r][side] is not None:
+                tb.send(r, around[r][side])
+
+    for _ in range(iterations):
+        for _k in range(planes):               # jacld + blts
+            for r in range(n_nodes):
+                sweep(r, "nw", "se")
+        for _k in range(planes):               # jacu + buts
+            for r in range(n_nodes):
+                sweep(r, "se", "nw")
+        for r in range(n_nodes):               # rhs: exchange_3, fluxes
+            for side in "nswe":
+                if around[r][side] is not None:
+                    tb.send(r, around[r][side])
+            for side in "nswe":
+                if around[r][side] is not None:
+                    tb.recv(r, around[r][side])
+            tb.compute(r, planes * plane_w[r] * _skew(rng, 0.1),
+                       cpu_frac=0.60)
+    tb.collective("allreduce", list(range(n_nodes)))   # l2norm
+    return tb
+
+
+def lu_like(n_nodes: int, klass: str = "A", iterations: int = 2,
+            nz: int = LU_GRID, seed: int = 6) -> JobDependencyGraph:
+    """Lower-upper SSOR analogue (NPB LU, RNR-91-002; MPI code NAS-95-020).
+
+    Each iteration: the lower sweep over the ``nz − 2`` k-planes, each
+    rank receiving from north and west, computing and sending south and
+    east; the upper sweep in reverse (receive south and east, send north
+    and west); then ``rhs``, a halo exchange with every neighbour and a
+    flux computation.  One allreduce (``l2norm``) ends the run.  The
+    wavefront is point-to-point: while it fills and drains most ranks
+    wait on a neighbour, and their power can go to the ranks on the
+    front.  Each plane is one compute job and up to three zero-work
+    send/recv markers (see :class:`TraceBuilder`).  NPB times 250
+    iterations at classes A to C; ``iterations`` defaults to 2, one whole
+    period of the loop body and the boundary where ``rhs`` releases the
+    next lower sweep.
+    """
+    return lu_builder(n_nodes, klass, iterations, nz, seed).build()
+
+
 def pipeline_graph(stages: int, microbatches: int, fwd_work: float = 4.0,
                    bwd_work: float = 8.0, skew: float = 0.0,
                    seed: int = 4) -> JobDependencyGraph:

@@ -291,7 +291,6 @@ class SweepService:
         self._outstanding = 0
         self._idle = threading.Condition(self._lock)
         self._jax_align: Optional[int] = None
-        self._dims_cache: Dict[tuple, tuple] = {}
         self._bucket_seq = itertools.count()
         self._req_seq = itertools.count()
 
@@ -442,9 +441,8 @@ class SweepService:
         extended with the power-of-two *minor* dims and the schedule
         column count, so the dispatched shapes — and therefore the jit
         signature — are a pure function of the key."""
-        base = bucket_key(backend, s, self._dims_cache)
-        minor = tuple(next_pow2(d)
-                      for d in scenario_dims(s, self._dims_cache)[2:])
+        base = bucket_key(backend, s)
+        minor = tuple(next_pow2(d) for d in scenario_dims(s)[2:])
         sched = next_pow2(len(s.bound_schedule)) \
             if s.bound_schedule else 0
         return base + (minor, sched)

@@ -185,3 +185,71 @@ def test_listing2_uniform_structure():
     g = listing2_uniform(10.0)
     assert g.makespan(NOMINAL) > 0
     assert g.max_depths() == listing2_graph().max_depths()
+
+
+class TestDerived:
+    """Values built from a graph alone are kept with it until a job is
+    added, and travel without it."""
+
+    def test_built_once_and_dropped_on_add(self):
+        calls = []
+        g = listing2_graph()
+
+        def build(graph):
+            calls.append(len(graph))
+            return len(graph)
+
+        assert g.derived("n", build) == g.derived("n", build) == len(g)
+        assert calls == [len(g)]
+        g.add(99, 0, 1.0)
+        assert g.derived("n", build) == len(g)
+        assert calls == [len(g) - 1, len(g)]
+
+    def test_pickled_graph_leaves_them_behind(self):
+        import pickle
+
+        g = listing2_graph()
+        g.derived("n", len)
+        h = pickle.loads(pickle.dumps(g))
+        assert h._derived == {} and g._derived == {"n": len(g)}
+        assert h.to_text() == g.to_text()
+
+    @pytest.mark.parametrize("case", ["listing2", "lu", "gaps"])
+    def test_lane_arrays_match_a_plain_build(self, case):
+        """The lanes are each node's jobs in index order, whatever the
+        node ids; the arrays are kept and read-only."""
+        import numpy as np
+
+        from repro.core.batchsim import lane_arrays
+        from repro.core.workloads import lu_like
+
+        if case == "listing2":
+            g = listing2_graph()
+        elif case == "lu":
+            g = lu_like(16, "B", iterations=1, nz=5)
+        else:                      # sparse node ids, lanes of unequal length
+            g = JobDependencyGraph()
+            g.add(7, 3, 1.0)
+            g.add(7, 1, 2.0, deps=[(2, 0)])
+            g.add(2, 0, 3.0, cpu_frac=0.5)
+        job_ids, work, rho, node_seq, deps = lane_arrays(g)
+        assert lane_arrays(g)[3] is node_seq
+        j = len(g)
+        assert list(job_ids) == sorted(g.jobs)
+        k_of = {jid: k for k, jid in enumerate(job_ids)}
+        lanes = [[k_of[job.job_id] for job in g.node_jobs(nid)]
+                 for nid in g.nodes]
+        assert node_seq.shape == (len(lanes),
+                                  max(len(s) for s in lanes) + 1)
+        for row, lane in zip(node_seq, lanes):
+            assert list(row[:len(lane)]) == lane
+            assert (row[len(lane):] == j).all()
+        for k, jid in enumerate(job_ids):
+            assert work[k] == g[jid].work and rho[k] == g[jid].cpu_frac
+            assert sorted(deps[k][deps[k] < j]) == sorted(
+                k_of[d] for d in g[jid].deps)
+        for a in (work, rho, node_seq, deps):
+            assert not a.flags.writeable
+            with pytest.raises(ValueError):
+                a[0] = 0
+        assert (work[j], rho[j]) == (0.0, 1.0)

@@ -439,16 +439,17 @@ class TestReadiness:
                     & completed[np.arange(n_states)[:, None, None],
                                 deps_pad[cur]].all(axis=-1))
 
-            def mask(p, c, run):
+            def mask(p, run):
                 st = engine._RowState(
                     ptr=p, running=run, remaining=jnp.ones(n),
-                    completed=c, row_t=0.0, bound=1.0, sched_idx=0,
+                    row_t=0.0, bound=1.0, sched_idx=0,
                     done=jnp.zeros((), bool), stalled=False, energy=0.0,
-                    peak=0.0, over_t=0.0, makespan=0.0, start_t=None,
-                    end_t=None, tick_count=0, steps=0)
+                    peak=0.0, over_t=0.0, makespan=0.0, start_at=None,
+                    end_at=None, tick_count=0, steps=0,
+                    settle_rounds=0)
                 return engine._ready_mask(ctx_r, st)
 
-            got = np.asarray(jax.vmap(mask)(ptr, completed, running))
+            got = np.asarray(jax.vmap(mask)(ptr, running))
             np.testing.assert_array_equal(got, want)
             ready_seen += int(want.sum())
             blocked_seen += int((~want & ~running & (cur < j)).sum())
@@ -495,3 +496,130 @@ class TestReadiness:
                     b.job_starts[jid], rel=1e-5, abs=1e-5), jid
                 assert a.job_ends[jid] == pytest.approx(
                     b.job_ends[jid], rel=1e-5, abs=1e-5), jid
+
+
+def _lu_batch(policy="equal-share", work=True):
+    """A 16-rank NPB LU wavefront (send/recv markers on every lane), or
+    the same graph with every job's work zeroed, as a warm-up runs it."""
+    from repro.core import JobDependencyGraph
+    from repro.core.workloads import lu_like
+
+    g = lu_like(16, "B", iterations=1, nz=6, seed=4)
+    if not work:
+        g0 = JobDependencyGraph()
+        for job in g.jobs.values():
+            g0.add(job.node, job.index, 0.0, deps=list(job.deps),
+                   cpu_frac=job.cpu_frac, tag=job.tag)
+        g = g0
+    return JaxBatchSimulator(g, homogeneous_cluster(16), [40.0, 90.0],
+                             policy=policy)
+
+
+class TestLaneState:
+    """The settle loop carries lane state only: stamps are written per
+    wave by lane position and laid out by job when the row ends, and a
+    loop iteration unrolls several settle rounds."""
+
+    @pytest.mark.parametrize("policy", ["equal-share", "oracle"])
+    def test_lu_stamps_match_numpy_backend(self, policy):
+        """Every job's start and end on a point-to-point wavefront, zero-
+        work markers included, agree with the numpy batch backend's."""
+        sim = _lu_batch(policy)
+        g, specs = sim.graph, sim.specs
+        vec = simulate_batch(g, specs, list(sim.bounds), policy)
+        for a, b in zip(sim.run(), vec):
+            assert a.job_starts.keys() == b.job_starts.keys() == g.jobs.keys()
+            assert a.job_ends.keys() == b.job_ends.keys()
+            for jid in g.jobs:
+                assert a.job_starts[jid] == pytest.approx(
+                    b.job_starts[jid], rel=1e-5, abs=1e-5), jid
+                assert a.job_ends[jid] == pytest.approx(
+                    b.job_ends[jid], rel=1e-5, abs=1e-5), jid
+
+    @pytest.mark.parametrize("policy", ["equal-share", "oracle"])
+    def test_unrolled_settle_changes_no_output(self, policy, monkeypatch):
+        """Unrolling settle rounds is a schedule, not a result: every
+        output, the settle rounds included, is bit for bit the one a
+        round an iteration gives."""
+        import functools
+
+        import numpy as np
+
+        from repro.backends.jax import engine
+
+        args, statics = _lu_batch(policy)._pack()
+        outs = []
+        for unroll in (1, engine.SETTLE_UNROLL):
+            monkeypatch.setattr(engine, "SETTLE_UNROLL", unroll)
+            step = jax.jit(functools.partial(engine._vmapped_rows,
+                                             **statics))
+            out = jax.device_get(step(*args))
+            outs.append({k: np.asarray(v) for k, v in out.items()})
+        assert engine.SETTLE_UNROLL > 1
+        for k, v in outs[0].items():
+            if k in ("start_t", "end_t"):      # the sentinel slot is junk
+                v, w = v[:, :-1], outs[1][k][:, :-1]
+            else:
+                w = outs[1][k]
+            np.testing.assert_array_equal(v, w, err_msg=k)
+        assert outs[0]["settle_rounds"].min() > outs[0]["steps"].max()
+
+    @pytest.mark.parametrize("graph", ["lu", "is"])
+    def test_zeroed_graph_compiles_the_real_graphs_stepper(self, graph):
+        """A benchmark warms up on its graphs with the work zeroed (every
+        job a zero-work one); the real graphs must then find the stepper
+        compiled, whatever runs of zero-work jobs they hold."""
+        from repro.backends.jax import engine
+        from repro.core import JobDependencyGraph
+        from repro.core.workloads import is_like
+
+        if graph == "lu":
+            warm, real = _lu_batch(work=False), _lu_batch()
+        else:
+            g = is_like(16, seed=7)
+            g0 = JobDependencyGraph()
+            for job in g.jobs.values():
+                g0.add(job.node, job.index, 0.0, deps=list(job.deps),
+                       cpu_frac=job.cpu_frac, tag=job.tag)
+            warm, real = (JaxBatchSimulator(x, homogeneous_cluster(16),
+                                            [40.0, 90.0]) for x in (g0, g))
+        assert warm.arrays.work_pad.max() == 0 < real.arrays.work_pad.max()
+        warm.run()
+        compiled = engine.stepper_cache_size()
+        pending = real.dispatch()
+        real.fetch(pending)
+        assert not pending.profile.compiled
+        assert engine.stepper_cache_size() == compiled
+
+    def test_shared_batches_of_one_graph_share_its_tables(self):
+        """A sweep that runs a graph again (another pass, another policy)
+        reuses the lane arrays and readiness table kept with the graph;
+        adding a job rebuilds them."""
+        a, b = _lu_batch(), _lu_batch("oracle")
+        assert a.graph is not b.graph           # two builds of the graph
+        c = JaxBatchSimulator(a.graph, a.specs, [60.0], policy="oracle")
+        assert c.need is a.need and not c.need.flags.writeable
+        assert c.arrays.node_seq is a.arrays.node_seq
+        assert b.need is not a.need
+        a.graph.add(99, 0, 1.0)
+        d = JaxBatchSimulator(a.graph, list(a.specs) + a.specs[:1], [60.0])
+        assert d.need.shape == (a.need.shape[0] + 1, a.need.shape[1] + 1)
+        assert d.run()[0].makespan > 0
+
+    def test_deadlock_names_only_the_jobs_that_never_ran(self):
+        """``completed`` is laid out from the lanes' progress when the
+        row ends: the job that ran before the stall is not reported."""
+        from repro.core import JobDependencyGraph
+
+        g = JobDependencyGraph()
+        g.add(0, 0, 1.0)
+        g.add(0, 1, 1.0, deps=[(1, 2)])
+        g.add(0, 2, 1.0)
+        g.add(1, 1, 1.0, deps=[(0, 2)])
+        g.add(1, 2, 1.0)
+        with pytest.raises(RuntimeError, match="deadlock") as err:
+            simulate_batch_jax(g, homogeneous_cluster(2), [6.0])
+        msg = str(err.value)
+        assert "(0, 0)" not in msg
+        for jid in ("(0, 1)", "(0, 2)", "(1, 1)", "(1, 2)"):
+            assert jid in msg
