@@ -163,6 +163,7 @@ class _RowState(NamedTuple):
     tick_count: jnp.ndarray  # scalar int32
     steps: jnp.ndarray      # scalar int32
     settle_rounds: jnp.ndarray  # scalar int32: _settle's rounds
+    waterfill_rounds: jnp.ndarray  # scalar int32: the water-fill's rounds
 
 
 def _cur(ctx: _Ctx, st: _RowState) -> jnp.ndarray:
@@ -284,7 +285,8 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
         start_at=jnp.full(ctx.node_seq.shape, jnp.nan, ftype),
         end_at=jnp.full(ctx.node_seq.shape, jnp.nan, ftype),
         tick_count=jnp.zeros((), jnp.int32), steps=jnp.zeros((), jnp.int32),
-        settle_rounds=jnp.zeros((), jnp.int32))
+        settle_rounds=jnp.zeros((), jnp.int32),
+        waterfill_rounds=jnp.zeros((), jnp.int32))
     st0 = _stamp(ctx, _settle(ctx, st0), st0.ptr, _started(st0))
 
     def cond(carry):
@@ -295,7 +297,7 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
         st, pol = carry
         ptr0, started0 = st.ptr, _started(st)
         caps = cls.caps_fn(ctx, st, pol)
-        rate2, _, t_fin2, _, p_cl2, t_comp2 = power_step(
+        rate2, _, t_fin2, _, p_cl2, t_comp2, wf2 = power_step(
             ctx.tab, caps[None, :].astype(ftype),
             st.running[None, :].astype(ftype), st.remaining[None, :],
             ctx.rho_pad[_cur(ctx, st)][None, :],
@@ -345,7 +347,8 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
             peak=jnp.maximum(st.peak, p_cluster),
             over_t=st.over_t + jnp.where(over, delta, 0.0),
             stalled=st.stalled | stalled_now,
-            steps=st.steps + 1)
+            steps=st.steps + 1,
+            waterfill_rounds=st.waterfill_rounds + wf2[0, 0])
         st = _complete(st, finishing)
         if wants_ticks:
             pol = cls.tick_fn(ctx, st, pol, due)
@@ -364,6 +367,7 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
         "completed": ctx.completed0 | _by_job(ctx, done_at, False),
         "done": st.done, "stalled": st.stalled,
         "steps": st.steps, "settle_rounds": st.settle_rounds,
+        "waterfill_rounds": st.waterfill_rounds,
     }
 
 
@@ -840,8 +844,9 @@ class JaxBatchSimulator:
         The whole output pytree comes back in ONE fused device-to-host
         transfer (``jax.device_get``) — never one sync per field — and
         shard-padding phantom rows are trimmed before any bookkeeping.
-        The profile counts the batch's waves from the rows' ``steps``
-        and their settle rounds from ``settle_rounds``.
+        The profile counts the batch's waves from the rows' ``steps``,
+        their settle rounds from ``settle_rounds`` and their water-fill
+        rounds from ``waterfill_rounds``.
         """
         prof = pending.profile
         args = {"bucket": prof.bucket, "rows": self.n_rows,
@@ -859,10 +864,14 @@ class JaxBatchSimulator:
             np.asarray(out["steps"]), self.n_rows, self.n_shards)
         prof.settle_rounds = int(
             np.asarray(out["settle_rounds"])[:self.n_rows].sum())
+        prof.waterfill_rounds = int(
+            np.asarray(out["waterfill_rounds"])[:self.n_rows].sum())
         with obs_trace.region("results", "engine", waves=prof.waves,
                               row_waves=prof.row_waves,
                               row_slots=prof.row_slots,
-                              settle_rounds=prof.settle_rounds, **args):
+                              settle_rounds=prof.settle_rounds,
+                              waterfill_rounds=prof.waterfill_rounds,
+                              **args):
             out = {k: np.asarray(v)[:self.n_rows] for k, v in out.items()}
             self._check_failures(out)
             return self._results(out)

@@ -299,9 +299,9 @@ class JaxOnlineHeuristic(JaxPolicy):
         delay = depth - 1
         running = st.running[None, :]
         idle_draw = jnp.sum(jnp.where(running, 0.0, ctx.tab.idle_w))
-        target = waterfill_caps(
-            ctx.tab, running,
-            jnp.reshape(st.bound - idle_draw, (1, 1)))[0]
+        caps, _ = waterfill_caps(
+            ctx.tab, running, jnp.reshape(st.bound - idle_draw, (1, 1)))
+        target = caps[0]
         slot = st.tick_count % depth
         buf = jnp.where(due, pol["buf"].at[slot].set(target), pol["buf"])
         ticks = st.tick_count + 1

@@ -66,6 +66,10 @@ class BucketProfile:
     #: the settle before the first wave included (÷ ``row_waves``:
     #: rounds a wave)
     settle_rounds: int = 0
+    #: live rounds of the oracle's water-fill summed over the real rows
+    #: (0 where the bucket does not redistribute; ÷ ``row_waves``:
+    #: rounds a wave)
+    waterfill_rounds: int = 0
 
     def to_dict(self) -> Dict[str, object]:
         """Flat JSON-ready payload (BENCH records embed these)."""
@@ -80,6 +84,7 @@ class BucketProfile:
             "waves": self.waves, "row_waves": self.row_waves,
             "row_slots": self.row_slots,
             "settle_rounds": self.settle_rounds,
+            "waterfill_rounds": self.waterfill_rounds,
         }
 
 

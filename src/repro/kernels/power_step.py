@@ -132,19 +132,30 @@ def translate_caps(tab: StepTables, caps: jnp.ndarray
 
 
 def waterfill_caps(tab: StepTables, running: jnp.ndarray,
-                   budget: jnp.ndarray) -> jnp.ndarray:
+                   budget: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Water-fill ``budget`` (``(1, 1)``) over the running lanes of one
     row: equal shares, saturated lanes clamp at ``p_max``, the surplus
     re-spreads until absorbed; non-running lanes get the cap floor.
     Row-for-row the same fixed point as
-    :func:`repro.policies.vector.batched_waterfill` (the loop is
-    unrolled ``N`` times — each live iteration closes at least one
-    lane)."""
+    :func:`repro.policies.vector.batched_waterfill`.
+
+    Returns ``(caps, rounds)``: the caps ``(1, N)`` and the live rounds
+    run, an int32 scalar.  A round closes every open lane whose
+    ``p_max`` fits the share (the lowest levels left), or all of them
+    once none does, so the loop stops when no lane is open: after at
+    most as many rounds as there are distinct ``p_max`` values among
+    the running lanes, never more than ``N``.  A round past that fixed
+    point would change nothing.  The open mask is carried as int32: the
+    TPU kernel compiler cannot carry a boolean vector through a loop."""
     n = running.shape[-1]
-    caps = jnp.broadcast_to(tab.cap_floor, running.shape)
-    open_ = running
-    rem = budget
-    for _ in range(n):
+
+    def go(carry):
+        _, open_, _, rounds = carry
+        return jnp.any(open_ > 0) & (rounds < n)
+
+    def body(carry):
+        caps, open_, rem, rounds = carry
+        open_ = open_ > 0
         n_open = jnp.sum(open_, axis=-1, keepdims=True)
         live = n_open > 0
         share = jnp.where(live, rem / jnp.maximum(n_open, 1), 0.0)
@@ -156,19 +167,27 @@ def waterfill_caps(tab: StepTables, running: jnp.ndarray,
         rem = rem - jnp.sum(jnp.where(sat, tab.p_max, 0.0), axis=-1,
                             keepdims=True)
         open_ = open_ & ~sat & ~finished
-    return caps
+        return caps, open_.astype(jnp.int32), rem, rounds + 1
+
+    caps = jnp.broadcast_to(tab.cap_floor, running.shape)
+    caps, _, _, rounds = jax.lax.while_loop(
+        go, body, (caps, running.astype(jnp.int32), budget,
+                   jnp.zeros((), jnp.int32)))
+    return caps, rounds
 
 
 def _step_math(tab: StepTables, caps, running, remaining, rho, bound,
                redistribute: bool):
     """The fused wave: shared verbatim by the reference and the kernel
-    body (the kernel only differs in how operands arrive)."""
+    body (the kernel only differs in how operands arrive).  The last
+    output is the water-fill's live rounds, ``(1, 1)`` int32 (0 when
+    ``redistribute`` is off)."""
     if redistribute:
         idle_draw = jnp.sum(jnp.where(running, 0.0, tab.idle_w), axis=-1,
                             keepdims=True)
-        eff_caps = waterfill_caps(tab, running, bound - idle_draw)
+        eff_caps, rounds = waterfill_caps(tab, running, bound - idle_draw)
     else:
-        eff_caps = caps
+        eff_caps, rounds = caps, jnp.zeros((), jnp.int32)
     freq, duty, power = translate_caps(tab, eff_caps)
     slowdown = rho * (tab.f_nom / freq) + (1.0 - rho)
     rate = jnp.where(running, tab.speed * duty / slowdown, 0.0)
@@ -178,14 +197,16 @@ def _step_math(tab: StepTables, caps, running, remaining, rho, bound,
                       remaining / jnp.where(has_rate, rate, 1.0), BIG_TIME)
     p_cluster = jnp.sum(p_node, axis=-1, keepdims=True)
     t_comp = jnp.min(t_fin, axis=-1, keepdims=True)
-    return rate, p_node, t_fin, eff_caps, p_cluster, t_comp
+    return (rate, p_node, t_fin, eff_caps, p_cluster, t_comp,
+            jnp.full((1, 1), rounds, jnp.int32))
 
 
 def power_step_ref(tab: StepTables, caps, running, remaining, rho, bound,
                    redistribute: bool = False):
     """Pure-jnp reference: caps/running/remaining/rho ``(1, N)``, bound
-    ``(1, 1)`` -> ``(rate, p_node, t_fin, eff_caps, p_cluster, t_comp)``
-    with lane shapes ``(1, N)`` and row scalars ``(1, 1)``.  ``running``
+    ``(1, 1)`` -> ``(rate, p_node, t_fin, eff_caps, p_cluster, t_comp,
+    waterfill_rounds)`` with lane shapes ``(1, N)`` and row scalars
+    ``(1, 1)`` (the rounds int32).  ``running``
     is a float mask (1.0 running / 0.0 not) for kernel parity."""
     return _step_math(tab, caps, running > 0.5, remaining, rho, bound,
                       redistribute)
@@ -197,13 +218,13 @@ def _power_step_kernel(caps_ref, running_ref, remaining_ref, rho_ref,
                        f_min_ref, f_nom_ref, span_ref, speed_ref,
                        floor_ref, p_max_ref, rate_ref, p_node_ref,
                        t_fin_ref, eff_caps_ref, p_cluster_ref, t_comp_ref,
-                       *, redistribute: bool):
+                       rounds_ref, *, redistribute: bool):
     tab = StepTables(
         state_p=state_p_ref[...], state_f=state_f_ref[...],
         idle_w=idle_ref[...], f_min=f_min_ref[...], f_nom=f_nom_ref[...],
         span=span_ref[...], speed=speed_ref[...],
         cap_floor=floor_ref[...], p_max=p_max_ref[...])
-    rate, p_node, t_fin, eff_caps, p_cluster, t_comp = _step_math(
+    rate, p_node, t_fin, eff_caps, p_cluster, t_comp, rounds = _step_math(
         tab, caps_ref[...], running_ref[...] > 0.5, remaining_ref[...],
         rho_ref[...], bound_ref[...], redistribute)
     rate_ref[...] = rate
@@ -212,6 +233,7 @@ def _power_step_kernel(caps_ref, running_ref, remaining_ref, rho_ref,
     eff_caps_ref[...] = eff_caps
     p_cluster_ref[...] = p_cluster
     t_comp_ref[...] = t_comp
+    rounds_ref[...] = rounds
 
 
 def power_step_pallas(tab: StepTables, caps, running, remaining, rho,
@@ -232,7 +254,8 @@ def power_step_pallas(tab: StepTables, caps, running, remaining, rho,
     scalar = jax.ShapeDtypeStruct((1, 1), dtype)
     return pl.pallas_call(
         functools.partial(_power_step_kernel, redistribute=redistribute),
-        out_shape=(lane, lane, lane, lane, scalar, scalar),
+        out_shape=(lane, lane, lane, lane, scalar, scalar,
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)),
         interpret=interpret,
     )(caps, running, remaining, rho, bound, tab.state_p, tab.state_f,
       tab.idle_w, tab.f_min, tab.f_nom, tab.span, tab.speed,

@@ -446,7 +446,7 @@ class TestReadiness:
                     done=jnp.zeros((), bool), stalled=False, energy=0.0,
                     peak=0.0, over_t=0.0, makespan=0.0, start_at=None,
                     end_at=None, tick_count=0, steps=0,
-                    settle_rounds=0)
+                    settle_rounds=0, waterfill_rounds=0)
                 return engine._ready_mask(ctx_r, st)
 
             got = np.asarray(jax.vmap(mask)(ptr, running))
@@ -623,3 +623,76 @@ class TestLaneState:
         assert "(0, 0)" not in msg
         for jid in ("(0, 1)", "(0, 2)", "(1, 1)", "(1, 2)"):
             assert jid in msg
+
+
+def _is8_oracle(policy="oracle"):
+    """IS at 8 ranks on the two stock tables, two bounds: a small NPB
+    bucket whose oracle waves water-fill over two ``p_max`` levels."""
+    from repro.core import heterogeneous_cluster, is_like
+    from repro.core.power import (max_useful_cluster_bound,
+                                  min_feasible_cluster_bound)
+
+    g = is_like(8, "A", iterations=2, seed=3)
+    specs = heterogeneous_cluster(8, seed=3)
+    lo = min_feasible_cluster_bound(specs)
+    hi = max_useful_cluster_bound(specs)
+    return JaxBatchSimulator(g, specs, [lo + f * (hi - lo)
+                                        for f in (0.25, 0.6)],
+                             policy=policy)
+
+
+def _stamp_digest(res):
+    """First 16 hex digits of the SHA-256 of a result's job starts then
+    ends (float64, jobs in sorted id order)."""
+    import hashlib
+
+    import numpy as np
+
+    ids = sorted(res.job_starts)
+    at = np.array([[res.job_starts[i] for i in ids],
+                   [res.job_ends[i] for i in ids]], np.float64)
+    return hashlib.sha256(at.tobytes()).hexdigest()[:16]
+
+
+#: ``_is8_oracle()``'s results from the stepper whose water-fill unrolled
+#: all N rounds on every wave (before the loop stopped at its fixed
+#: point), on the CPU: per row the makespan and energy (``float.hex``)
+#: and ``_stamp_digest``.  The while-loop must give the same bits.
+IS8_ORACLE = [("0x1.66b2800000000p+5", "0x1.b6c0980000000p+9",
+               "0fe4a48013aa6737"),
+              ("0x1.3608200000000p+5", "0x1.4c4a600000000p+10",
+               "2956da714718b5eb")]
+
+
+class TestWaterfillRounds:
+    @pytest.mark.parametrize("policy", ["equal-share", "oracle"])
+    def test_bucket_counts_its_fill_rounds(self, policy, monkeypatch):
+        """Oracle waves water-fill in one or two rounds on two tables
+        (at most 3 a wave by the issue's bound); equal-share never
+        fills.  The count is an arg of the ``results`` region."""
+        from repro.obs import trace
+
+        seen = []
+        real = trace.region
+
+        def recording(name, track, *a, **kw):
+            seen.append((name, kw))
+            return real(name, track, *a, **kw)
+
+        monkeypatch.setattr(trace, "region", recording)
+        sim = _is8_oracle(policy)
+        pending = sim.dispatch()
+        sim.fetch(pending)
+        prof = pending.profile
+        if policy == "oracle":
+            assert 0 < prof.waterfill_rounds <= 3 * prof.row_waves
+        else:
+            assert prof.waterfill_rounds == 0
+        assert prof.to_dict()["waterfill_rounds"] == prof.waterfill_rounds
+        args, = [kw for name, kw in seen if name == "results"]
+        assert args["waterfill_rounds"] == prof.waterfill_rounds
+
+    def test_oracle_results_keep_the_unrolled_fills_bits(self):
+        got = [(r.makespan.hex(), r.energy_j.hex(), _stamp_digest(r))
+               for r in _is8_oracle().run()]
+        assert got == IS8_ORACLE

@@ -118,6 +118,69 @@ class TestSSMScan:
                                    rtol=1e-4, atol=1e-4)
 
 
+def _unrolled_waterfill(tab, running, budget):
+    """The water-fill as it was unrolled before its loop stopped at the
+    fixed point: ``N`` copies of the round, whether live or not."""
+    from repro.kernels.power_step import FIT_ATOL
+
+    caps = jnp.broadcast_to(tab.cap_floor, running.shape)
+    open_ = running
+    rem = budget
+    for _ in range(running.shape[-1]):
+        n_open = jnp.sum(open_, axis=-1, keepdims=True)
+        live = n_open > 0
+        share = jnp.where(live, rem / jnp.maximum(n_open, 1), 0.0)
+        sat = open_ & (tab.p_max <= share + FIT_ATOL)
+        finished = live & ~jnp.any(sat, axis=-1, keepdims=True)
+        caps = jnp.where(open_ & finished,
+                         jnp.clip(share, tab.cap_floor, tab.p_max), caps)
+        caps = jnp.where(sat, tab.p_max, caps)
+        rem = rem - jnp.sum(jnp.where(sat, tab.p_max, 0.0), axis=-1,
+                            keepdims=True)
+        open_ = open_ & ~sat & ~finished
+    return caps
+
+
+#: Water-fill inputs: the first is the original mixed draw.
+WATERFILL_CASES = ["mixed", "idle", "all-saturate", "below-floors",
+                   "phantom", "two-pmax", "distinct-pmax"]
+
+
+def _waterfill_case(case, rows=32):
+    """``(table, tab, running (rows, N) bool, budget (rows,), two_tables)``
+    for one water-fill case; ``two_tables`` marks the clusters of the
+    two stock tables, whose caps must match the unrolled loop bitwise."""
+    from repro.core.power import (heterogeneous_cluster, lut_table,
+                                  stack_lut_tables)
+    from repro.kernels.power_step import step_tables
+
+    rng = np.random.default_rng(9)
+    n = {"two-pmax": 64, "distinct-pmax": 48}.get(case, 5)
+    table = lut_table(heterogeneous_cluster(n, seed=0))
+    tab = step_tables(table)
+    p_sum = float(table.p_max.sum())
+    running = rng.random((rows, n)) < 0.6
+    budget = rng.uniform(0.0, p_sum, rows)
+    if case == "idle":
+        running[:] = False
+    elif case == "all-saturate":
+        running[:] = True
+        budget = rng.uniform(p_sum, 2.0 * p_sum, rows)
+    elif case == "below-floors":
+        budget = rng.uniform(0.0, float(table.cap_floor.min()), rows)
+    elif case == "phantom":
+        stacked = stack_lut_tables([table], n + 3, table.state_p.shape[1])
+        tab = type(tab)(*(a[0] for a in step_tables(stacked)))
+        running = np.concatenate([running, np.zeros((rows, 3), bool)], 1)
+    elif case == "distinct-pmax":
+        # every lane its own saturation level, floors kept below
+        p_max = np.asarray(tab.p_max) * rng.uniform(1.0, 1.5, (1, n))
+        tab = tab._replace(p_max=p_max.astype(np.float32))
+        budget = rng.uniform(0.0, float(p_max.sum()), rows)
+    return (table, tab, running, budget.astype(np.float32),
+            case != "distinct-pmax")
+
+
 class TestPowerStep:
     """Fused power-redistribution step: Pallas (interpret) vs jnp
     reference, and both vs the numpy translation/waterfill oracles."""
@@ -192,7 +255,7 @@ class TestPowerStep:
         remaining = rng.uniform(0.1, 50.0, (16, n))
         for i in range(16):
             f32 = lambda a: jnp.asarray(a[i:i + 1], jnp.float32)  # noqa: E731
-            rate, p_node, t_fin, eff, p_cl, t_comp = power_step_ref(
+            rate, p_node, t_fin, eff, p_cl, t_comp, _ = power_step_ref(
                 tab, f32(caps), jnp.ones((1, n), jnp.float32),
                 f32(remaining), f32(rho), jnp.ones((1, 1), jnp.float32))
             np.testing.assert_allclose(np.asarray(rate)[0], rate_np[i],
@@ -203,21 +266,62 @@ class TestPowerStep:
                                        (remaining[i] / rate_np[i]).min(),
                                        rtol=1e-4)
 
-    def test_waterfill_matches_numpy_oracle(self):
-        """waterfill_caps agrees with the vector backend's
-        batched_waterfill row for row."""
+    @pytest.mark.parametrize("case", WATERFILL_CASES)
+    def test_waterfill_matches_numpy_oracle(self, case):
+        """waterfill_caps under ``jit(vmap(...))``, as the engine runs
+        it, gives the caps of the N-fold unrolled loop it replaced, in
+        at most as many rounds as the running lanes have distinct
+        ``p_max`` values; the mixed case also agrees with the vector
+        backend's batched_waterfill row for row."""
         from repro.kernels.power_step import waterfill_caps
         from repro.policies.vector import batched_waterfill
 
-        _, table, tab = self._tables()
-        n = table.n_nodes
-        rng = np.random.default_rng(9)
-        running = rng.random((32, n)) < 0.6
-        budget = rng.uniform(0.0, float(table.p_max.sum()), 32)
-        want = batched_waterfill(running, budget, table)
-        for i in range(32):
-            got = waterfill_caps(
-                tab, jnp.asarray(running[i:i + 1]),
-                jnp.asarray(budget[i:i + 1, None], jnp.float32))
-            np.testing.assert_allclose(np.asarray(got)[0], want[i],
-                                       rtol=1e-5, atol=1e-5)
+        table, tab, running, budget, two_tables = _waterfill_case(case)
+        args = (tab, jnp.asarray(running[:, None, :]),
+                jnp.asarray(budget[:, None, None], jnp.float32))
+        got, rounds = jax.jit(jax.vmap(waterfill_caps,
+                                       in_axes=(None, 0, 0)))(*args)
+        want = jax.jit(jax.vmap(_unrolled_waterfill,
+                                in_axes=(None, 0, 0)))(*args)
+        got, want = np.asarray(got)[:, 0], np.asarray(want)[:, 0]
+        if two_tables:
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_array_max_ulp(got, want, maxulp=2)
+        p_max = np.asarray(tab.p_max)[0]
+        levels = np.array([len(np.unique(p_max[r])) for r in running])
+        rounds = np.asarray(rounds)
+        assert (rounds <= levels).all()
+        assert ((rounds > 0) == running.any(axis=1)).all()
+        if case == "mixed":
+            np.testing.assert_allclose(
+                got, batched_waterfill(running, budget, table),
+                rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("bound_frac", [0.3, 0.7, 1.2])
+    def test_pallas_matches_ref_through_fill_rounds(self, bound_frac):
+        """Oracle waves whose water-fill takes one or two rounds (two
+        node tables): the interpreted kernel and the reference agree,
+        rounds included."""
+        from repro.kernels.power_step import (power_step_pallas,
+                                              power_step_ref)
+
+        _, table, tab = self._tables(n=12, seed=4)
+        rows = [self._inputs(table, seed=s) for s in range(10, 16)]
+        caps, running, remaining, rho, _ = (jnp.stack(a)
+                                            for a in zip(*rows))
+        bound = jnp.full((len(rows), 1, 1),
+                         bound_frac * float(table.p_max.sum()), jnp.float32)
+        got = jax.vmap(lambda c, r, m, h, b: power_step_pallas(
+            tab, c, r, m, h, b, redistribute=True, interpret=True))(
+                caps, running, remaining, rho, bound)
+        want = jax.vmap(lambda c, r, m, h, b: power_step_ref(
+            tab, c, r, m, h, b, redistribute=True))(
+                caps, running, remaining, rho, bound)
+        for g, w in zip(got[:-1], want[:-1]):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-6, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(got[-1]),
+                                      np.asarray(want[-1]))
+        assert np.asarray(want[-1]).max() >= 1
+
