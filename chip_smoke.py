@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Run the compiled power-redistribution path once on a TPU.
 
-    python chip_smoke.py              # one chip: phases a-f
+    python chip_smoke.py              # one chip: phases a-c, e, f
     python chip_smoke.py --chips 4    # four chips: the sharded sweep only
 
 Phases, in one process (a chip belongs to one process at a time):
@@ -16,9 +16,6 @@ c. the plain reference: the event simulator (``core/simulator.py``), in
    spawned worker processes that never load JAX, against the jax
    results of the quick mixed family and of one bound of every NPB
    member at 64 ranks and of IS at 256 ranks.
-d. the Pallas ``power_step`` kernel compiled natively on one oracle
-   NPB-IS bucket at 256 ranks, against the same bucket on the jnp
-   reference.
 e. ``repro.launch.serve`` over ``examples/traces`` and the
    ``repro.cluster`` replay of ``examples/cluster/arrivals_1k.jsonl``,
    both with ``--expect-clean``.
@@ -322,46 +319,6 @@ def reference_pairs(quick_sweep, npb_sweep):
     return pairs
 
 
-def kernel_phase(clock: Clock, scenarios, native: bool = True) -> dict:
-    """Phase d: one padded oracle bucket through the Pallas kernel
-    (compiled natively when ``native``) and through the jnp reference;
-    the two must agree within the phase-c envelope."""
-    from repro.backends.jax import JaxBatchSimulator
-    from repro.core.sweep import next_pow2, scenario_dims
-
-    scenarios = [s for s in scenarios if s.policy == "oracle"]
-    check(bool(scenarios), "kernel phase: no oracle scenarios")
-    items = [(s.graph, list(s.specs)) for s in scenarios]
-    bounds = [s.bound_w for s in scenarios]
-    pad = tuple(next_pow2(max(col)) for col in
-                zip(*(scenario_dims(s) for s in scenarios)))
-    out = {}
-    for impl in ("pallas", "ref"):
-        with clock.phase(f"d.kernel.{impl}") as f:
-            kw = dict(use_kernel=True, kernel_interpret=not native) \
-                if impl == "pallas" else {}
-            sim = JaxBatchSimulator.padded(
-                items, bounds, policy="oracle", dt=DT,
-                latency_s=scenarios[0].latency_s, pad_dims=pad, **kw)
-            if impl == "pallas":
-                check(sim.kernel_interpret is (not native),
-                      f"kernel_interpret={sim.kernel_interpret}, "
-                      f"native={native}")
-            out[impl] = sim.run()
-            f.update(rows=len(bounds), ranks=pad[0], jobs_pad=pad[1],
-                     kernel_interpret=sim.kernel_interpret
-                     if impl == "pallas" else "-")
-    dm = max(abs(a.makespan - b.makespan)
-             for a, b in zip(out["pallas"], out["ref"]))
-    de = max(abs(a.energy_j - b.energy_j) / max(b.energy_j, 1e-12)
-             for a, b in zip(out["pallas"], out["ref"]))
-    print(f"[smoke] d.kernel: pallas vs ref max |dmakespan|={dm:.6g}s "
-          f"max |denergy|/energy={de:.6g}", flush=True)
-    check(dm <= MAKESPAN_ATOL and de <= ENERGY_RTOL,
-          f"pallas kernel vs ref: |dmakespan|={dm} |denergy|={de}")
-    return {"dmakespan_s": dm, "denergy_rel": de}
-
-
 def service_phase(clock: Clock, corpus=CORPUS, arrivals=ARRIVALS,
                   nodes: int = CLUSTER_NODES) -> None:
     """Phase e: the serve and cluster entry points, both clean."""
@@ -496,16 +453,13 @@ def sharded_phase(clock: Clock, scenarios, n_shards: int = SHARD_CHIPS):
 
 # -------------------------------------------------------------------- main
 def run_one_chip(clock: Clock) -> None:
-    """Phases b-f."""
+    """Phases b, c, e and f."""
     npb = npb_scenarios()
     npb_sweep = sweep_phase(clock, "npb", npb)
     print_buckets("npb.warm", npb_sweep)
     sweep_phase(clock, "family", family_grid(quick=False))
     quick = sweep_phase(clock, "quick", family_grid(quick=True))
     reference_phase(clock, reference_pairs(quick, npb_sweep))
-    is_big = [s for s in npb if s.tags.get("kind") == "is"
-              and n_ranks(s) == max(NPB_NODES)]
-    kernel_phase(clock, is_big)
     service_phase(clock)
     diff_phase(clock)
 

@@ -5,6 +5,7 @@ needs an accelerator stack lives here behind guarded imports.  Current
 backends:
 
 * :mod:`repro.backends.jax` — compiled wave-advancement engine
-  (``jax.lax.while_loop`` + ``vmap`` over the bound axis) with a fused
-  Pallas power-step kernel; ``SweepEngine(executor="jax")``.
+  (``jax.lax.while_loop`` + ``vmap`` over the bound axis) around the
+  plain-``jnp`` wave step :mod:`repro.kernels.power_step`;
+  ``SweepEngine(executor="jax")``.
 """

@@ -37,14 +37,13 @@ iteration, and a round past the fixed point changes nothing.
 Per wave, the hot path — LUT power->frequency gather, per-node rate
 computation, earliest-event reduction, and (for redistribution policies)
 idle-power reclamation/water-fill — is one call into
-:mod:`repro.kernels.power_step`: the pure-``jnp`` reference by default,
-or the fused Pallas kernel (``use_kernel=True``; interpret-mode on CPU).
-The row's *current* cluster bound is a traced operand of that call, so
-dynamic bound schedules flow straight through the kernel's
-reclamation/water-fill step: each row carries its padded ``(T,)``
-change-time/watt arrays, the wave advancement stops at the next arrival
-exactly like it stops at completions and policy ticks, and the updated
-bound feeds the very next wave's caps.
+:func:`repro.kernels.power_step.power_step`, plain ``jnp`` on the row's
+``(N,)`` lanes and scalars.  The row's *current* cluster bound is a
+traced operand of that call, so dynamic bound schedules flow straight
+through its reclamation/water-fill step: each row carries its padded
+``(T,)`` change-time/watt arrays, the wave advancement stops at the
+next arrival exactly like it stops at completions and policy ticks, and
+the updated bound feeds the very next wave's caps.
 
 Numerics: the engine runs in JAX's default float32.  Job completion is
 decided by *time* comparison (``t_fin <= delta``), never by a residual
@@ -101,8 +100,7 @@ from repro.core.graph import JobDependencyGraph
 from repro.core.power import NodeSpec
 from repro.core.simulator import OVER_BUDGET_RTOL, SimResult
 from repro.obs import trace as obs_trace
-from repro.kernels.power_step import (BIG_TIME, StepTables,
-                                      default_interpret, power_step,
+from repro.kernels.power_step import (BIG_TIME, StepTables, power_step,
                                       step_tables)
 
 from .policy_fns import (JaxPolicy, _JAX_REGISTRY, current_jobs,
@@ -269,7 +267,7 @@ def _by_job(ctx: _Ctx, at: jnp.ndarray, fill) -> jnp.ndarray:
 
 def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
               policy_name: str, wants_ticks: bool, redistribute: bool,
-              max_steps: int, impl: str, interpret: bool):
+              max_steps: int):
     cls = _JAX_REGISTRY[policy_name]
     n = ctx.node_seq.shape[0]
     t_cols = sched_t.shape[0]
@@ -297,14 +295,9 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
         st, pol = carry
         ptr0, started0 = st.ptr, _started(st)
         caps = cls.caps_fn(ctx, st, pol)
-        rate2, _, t_fin2, _, p_cl2, t_comp2, wf2 = power_step(
-            ctx.tab, caps[None, :].astype(ftype),
-            st.running[None, :].astype(ftype), st.remaining[None, :],
-            ctx.rho_pad[_cur(ctx, st)][None, :],
-            jnp.reshape(st.bound, (1, 1)), redistribute=redistribute,
-            impl=impl, interpret=interpret)
-        rate, t_fin = rate2[0], t_fin2[0]
-        p_cluster, t_comp = p_cl2[0, 0], t_comp2[0, 0]
+        rate, t_fin, p_cluster, t_comp, wf_rounds = power_step(
+            ctx.tab, caps.astype(ftype), st.running, st.remaining,
+            ctx.rho_pad[_cur(ctx, st)], st.bound, redistribute)
 
         if wants_ticks:
             next_tick = (st.tick_count + 1).astype(ftype) * ctx.dt
@@ -348,7 +341,7 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
             over_t=st.over_t + jnp.where(over, delta, 0.0),
             stalled=st.stalled | stalled_now,
             steps=st.steps + 1,
-            waterfill_rounds=st.waterfill_rounds + wf2[0, 0])
+            waterfill_rounds=st.waterfill_rounds + wf_rounds)
         st = _complete(st, finishing)
         if wants_ticks:
             pol = cls.tick_fn(ctx, st, pol, due)
@@ -372,19 +365,17 @@ def _row_loop(ctx: _Ctx, bound, sched_t, sched_w, pol_state, *,
 
 
 _STATIC_ARGS = ("policy_name", "wants_ticks", "redistribute",
-                "max_steps", "impl", "interpret", "stacked")
+                "max_steps", "stacked")
 
 
 def _vmapped_rows(ctx: _Ctx, bounds, sched_t, sched_w, pol_state, *,
                   policy_name: str, wants_ticks: bool, redistribute: bool,
-                  max_steps: int, impl: str, interpret: bool,
-                  stacked: bool):
+                  max_steps: int, stacked: bool):
     """The stepper vmapped over the (local) row axis — the shared body
     of the single-device and per-shard paths."""
     row = functools.partial(
         _row_loop, policy_name=policy_name, wants_ticks=wants_ticks,
-        redistribute=redistribute, max_steps=max_steps, impl=impl,
-        interpret=interpret)
+        redistribute=redistribute, max_steps=max_steps)
     ctx_axes = _CTX_ROW_AXES if stacked else None
     return jax.vmap(lambda c, b, t, w, p: row(c, b, t, w, p),
                     in_axes=(ctx_axes, 0, 0, 0, 0))(
@@ -397,12 +388,11 @@ def _vmapped_rows(ctx: _Ctx, bounds, sched_t, sched_w, pol_state, *,
 @functools.partial(jax.jit, static_argnames=_STATIC_ARGS)
 def _run_batch(ctx: _Ctx, bounds, sched_t, sched_w, pol_state, *,
                policy_name: str, wants_ticks: bool, redistribute: bool,
-               max_steps: int, impl: str, interpret: bool, stacked: bool):
+               max_steps: int, stacked: bool):
     return _vmapped_rows(
         ctx, bounds, sched_t, sched_w, pol_state,
         policy_name=policy_name, wants_ticks=wants_ticks,
-        redistribute=redistribute, max_steps=max_steps, impl=impl,
-        interpret=interpret, stacked=stacked)
+        redistribute=redistribute, max_steps=max_steps, stacked=stacked)
 
 
 @functools.lru_cache(maxsize=None)
@@ -427,8 +417,8 @@ def _ctx_specs(stacked: bool) -> _Ctx:
                    static_argnames=_STATIC_ARGS + ("n_shards",))
 def _run_batch_sharded(ctx: _Ctx, bounds, sched_t, sched_w, pol_state, *,
                        policy_name: str, wants_ticks: bool,
-                       redistribute: bool, max_steps: int, impl: str,
-                       interpret: bool, stacked: bool, n_shards: int):
+                       redistribute: bool, max_steps: int, stacked: bool,
+                       n_shards: int):
     """The stepper with the row axis sharded over ``n_shards`` devices.
 
     Each shard runs its own vmapped ``while_loop`` to completion with
@@ -438,8 +428,7 @@ def _run_batch_sharded(ctx: _Ctx, bounds, sched_t, sched_w, pol_state, *,
     """
     body = functools.partial(
         _vmapped_rows, policy_name=policy_name, wants_ticks=wants_ticks,
-        redistribute=redistribute, max_steps=max_steps, impl=impl,
-        interpret=interpret, stacked=stacked)
+        redistribute=redistribute, max_steps=max_steps, stacked=stacked)
     rows = P("rows")
     return jax.shard_map(body, mesh=_row_mesh(n_shards),
                          in_specs=(_ctx_specs(stacked), rows, rows, rows,
@@ -583,14 +572,10 @@ class JaxBatchSimulator:
     jax-policy registry (:mod:`repro.backends.jax.policy_fns`).
     ``bound_schedules`` (one ``(time_s, bound_w)`` iterable per row)
     makes the rows' cluster bounds time-varying, resolved at exact
-    arrival times inside the compiled loop.  ``use_kernel`` routes the
-    per-wave hot path through the fused Pallas kernel;
-    ``kernel_interpret`` defaults backend-detected (interpret on CPU,
-    native on GPU/TPU — see
-    :func:`repro.kernels.power_step.default_interpret`).
-    ``shard_devices`` shards the batch row axis across that many
-    visible devices (``None`` = all of them; with one device the
-    single-device vmap path runs unchanged).  Power traces are not
+    arrival times inside the compiled loop.  ``shard_devices`` shards
+    the batch row axis across that many visible devices (``None`` = all
+    of them; with one device the single-device vmap path runs
+    unchanged).  Power traces are not
     retained (``trace_every`` must be ``None``): sweeps that need
     traces belong on the numpy backends.
     """
@@ -600,8 +585,7 @@ class JaxBatchSimulator:
                  policy: Union[str, JaxPolicy] = "equal-share",
                  dt: float = 0.05, latency_s: float = 0.05,
                  trace_every: Optional[float] = None,
-                 max_steps: int = 1_000_000, use_kernel: bool = False,
-                 kernel_interpret: Optional[bool] = None,
+                 max_steps: int = 1_000_000,
                  bound_schedules: Optional[Sequence] = None,
                  shard_devices: Optional[int] = None,
                  **policy_kwargs):
@@ -611,8 +595,7 @@ class JaxBatchSimulator:
         self.graph = graph
         self.specs = list(specs)
         self._setup_run_params(bounds, policy, dt, latency_s, trace_every,
-                               max_steps, use_kernel, kernel_interpret,
-                               policy_kwargs, bound_schedules,
+                               max_steps, policy_kwargs, bound_schedules,
                                shard_devices)
         b = self.n_rows
         arrays = build_graph_arrays(graph, self.specs)
@@ -632,8 +615,7 @@ class JaxBatchSimulator:
                policy: Union[str, JaxPolicy] = "equal-share",
                dt: float = 0.05, latency_s: float = 0.05,
                trace_every: Optional[float] = None,
-               max_steps: int = 1_000_000, use_kernel: bool = False,
-               kernel_interpret: Optional[bool] = None,
+               max_steps: int = 1_000_000,
                bound_schedules: Optional[Sequence] = None,
                pad_dims: Optional[Tuple[int, int, int, int, int]] = None,
                shard_devices: Optional[int] = None,
@@ -647,8 +629,7 @@ class JaxBatchSimulator:
         self.graph = None
         self.specs = None
         self._setup_run_params(bounds, policy, dt, latency_s, trace_every,
-                               max_steps, use_kernel, kernel_interpret,
-                               policy_kwargs, bound_schedules,
+                               max_steps, policy_kwargs, bound_schedules,
                                shard_devices)
         arrays = stack_graph_arrays(items, pad_dims)
         self._init_rows(
@@ -680,8 +661,7 @@ class JaxBatchSimulator:
             arrays.node_seq, arrays.deps_pad)
 
     def _setup_run_params(self, bounds, policy, dt, latency_s, trace_every,
-                          max_steps, use_kernel, kernel_interpret,
-                          policy_kwargs, bound_schedules,
+                          max_steps, policy_kwargs, bound_schedules,
                           shard_devices=None) -> None:
         if dt <= 0:
             raise ValueError("dt must be positive")
@@ -695,10 +675,6 @@ class JaxBatchSimulator:
         self.dt = float(dt)
         self.latency_s = float(latency_s)
         self.max_steps = int(max_steps)
-        self.use_kernel = use_kernel
-        if kernel_interpret is None:
-            kernel_interpret = default_interpret()
-        self.kernel_interpret = bool(kernel_interpret)
         self.n_shards = shard_count(shard_devices, len(self.bounds))
         self._sched = pad_bound_schedules(bound_schedules, len(self.bounds))
         if isinstance(policy, JaxPolicy):
@@ -774,8 +750,6 @@ class JaxBatchSimulator:
             wants_ticks=self.policy.wants_ticks,
             redistribute=self.policy.redistribute,
             max_steps=self.max_steps,
-            impl="pallas" if self.use_kernel else "ref",
-            interpret=self.kernel_interpret,
             stacked=self.stacked)
         return (ctx, _to_device(bounds), _to_device(sched_t),
                 _to_device(sched_w), pol_state), statics

@@ -32,7 +32,7 @@ from typing import Dict, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.power_step import waterfill_caps
+from repro.kernels.power_step import reclaim_waterfill
 from repro.policies.registry import PolicyRegistry
 from repro.policies.vector import resolve_assignments
 
@@ -66,7 +66,7 @@ class JaxPolicy:
     Subclasses override the *host-side* hooks (``prepare`` once per
     batch, ``init_state`` for the per-row state pytree) and the *traced*
     staticmethods ``caps_fn`` / ``tick_fn``.  ``redistribute=True``
-    delegates cap computation to the fused power-step's reclamation /
+    delegates cap computation to the wave step's reclamation /
     water-fill stage instead of ``caps_fn`` (the oracle rule).
     """
 
@@ -201,7 +201,7 @@ class JaxIlpMakespan(JaxIlpStatic):
 class JaxOracle(JaxPolicy):
     """Zero-latency clairvoyant water-filling.
 
-    ``redistribute=True``: the fused power step reclaims non-running
+    ``redistribute=True``: the wave step reclaims non-running
     lanes' idle draw and water-fills the rest every wave, which at
     exact event times reproduces the event oracle's cap trajectory —
     ``caps_fn`` is never consulted for physics.
@@ -252,8 +252,8 @@ class JaxLearned(JaxPolicy):
             jnp, params, running=st.running,
             rho=jnp.where(st.running, rho, 0.0),
             bound=st.bound * 1.0, n_active=ctx.n_active * 1.0,
-            p_max=ctx.tab.p_max[0], cap_floor=ctx.tab.cap_floor[0],
-            idle_w=ctx.tab.idle_w[0])
+            p_max=ctx.tab.p_max, cap_floor=ctx.tab.cap_floor,
+            idle_w=ctx.tab.idle_w)
 
 
 @register_jax_policy("heuristic")
@@ -297,11 +297,7 @@ class JaxOnlineHeuristic(JaxPolicy):
         # heuristic's pre-increment slot / post-increment ripe check).
         depth = pol["buf"].shape[0]
         delay = depth - 1
-        running = st.running[None, :]
-        idle_draw = jnp.sum(jnp.where(running, 0.0, ctx.tab.idle_w))
-        caps, _ = waterfill_caps(
-            ctx.tab, running, jnp.reshape(st.bound - idle_draw, (1, 1)))
-        target = caps[0]
+        target, _ = reclaim_waterfill(ctx.tab, st.running, st.bound)
         slot = st.tick_count % depth
         buf = jnp.where(due, pol["buf"].at[slot].set(target), pol["buf"])
         ticks = st.tick_count + 1

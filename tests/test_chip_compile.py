@@ -1,7 +1,7 @@
-"""Compiles for a described TPU v5e: the Pallas ``power_step`` kernel and
-the compiled sweep stepper, built by the TPU compiler with no chip
-attached.  Nothing runs, so these say nothing of results or times; they
-catch what interpret mode cannot (tiling, fast-memory limits, lowering).
+"""Compiles for a described TPU v5e: the wave step ``power_step`` and the
+compiled sweep stepper, built by the TPU compiler with no chip attached.
+Nothing runs, so these say nothing of results or times; they catch what
+the CPU cannot (layouts, memory limits, lowering).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers import
@@ -18,15 +18,15 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from repro.backends.jax import JaxBatchSimulator  # noqa: E402
 from repro.backends.jax.engine import _run_batch  # noqa: E402
-from repro.core import is_like, heterogeneous_cluster  # noqa: E402
+from repro.core import heterogeneous_cluster, is_like, lu_like  # noqa: E402
 from repro.core.power import (max_useful_cluster_bound,  # noqa: E402
                               min_feasible_cluster_bound)
-from repro.core.sweep import next_pow2, scenario_dims, Scenario  # noqa: E402
-from repro.kernels.power_step import StepTables, power_step_pallas  # noqa: E402
+from repro.core.sweep import (Scenario, build_batch_sim,  # noqa: E402
+                              next_pow2, scenario_dims)
+from repro.kernels.power_step import StepTables, power_step  # noqa: E402
 
-#: Rows of the vmapped kernel call (a bucket's bound axis).
+#: Rows of the vmapped step (a bucket's bound axis).
 ROWS = 8
 #: LUT states, padded as the engine pads them.
 STATES = 16
@@ -60,38 +60,51 @@ def _abstract(tree, sharding):
 
 @pytest.mark.parametrize("redistribute", [False, True])
 @pytest.mark.parametrize("n", [12, 64, 256])
-def test_power_step_kernel_compiles(one_chip, n, redistribute):
-    def spec(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+def test_power_step_compiles(one_chip, n, redistribute):
+    """The step vmapped over a stacked bucket's rows, at the cells'
+    widths; only the redistributing step holds the water-fill's loop."""
+    def spec(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     tab = StepTables(spec(ROWS, STATES, n), spec(ROWS, STATES, n),
-                     *[spec(ROWS, 1, n)] * 7)
-    lane, scalar = spec(ROWS, 1, n), spec(ROWS, 1, 1)
+                     *[spec(ROWS, n)] * 7)
+    lane = spec(ROWS, n)
     step = jax.jit(jax.vmap(
-        lambda tab, caps, running, remaining, rho, bound: power_step_pallas(
-            tab, caps, running, remaining, rho, bound,
-            redistribute=redistribute, interpret=False)))
-    compiled = step.lower(tab, lane, lane, lane, lane, scalar).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        lambda tab, caps, running, remaining, rho, bound: power_step(
+            tab, caps, running, remaining, rho, bound, redistribute)))
+    compiled = step.lower(tab, lane, spec(ROWS, n, dtype=jnp.bool_), lane,
+                          lane, spec(ROWS)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert (" while(" in text) == redistribute
 
 
-@pytest.mark.parametrize("impl", ["pallas", "ref"])
-def test_sweep_stepper_compiles(one_chip, impl):
-    """One padded oracle NPB-IS bucket at 64 ranks, as the sweep engine
-    builds it, through the stepper the engine dispatches."""
-    graph = is_like(64, "B", seed=5)
-    specs = heterogeneous_cluster(64, seed=5)
+def _bucket(graph: str, policy: str):
+    """A two-bound bucket as the sweep engine builds it: IS-64 stacked
+    into its power-of-two envelope, LU-64 on its one shared graph."""
+    if graph == "is64":
+        g, shared = is_like(64, "B", seed=5), False
+    else:
+        g, shared = lu_like(64, "B", iterations=2), True
+    specs = tuple(heterogeneous_cluster(64, seed=5))
     lo = min_feasible_cluster_bound(specs)
     hi = max_useful_cluster_bound(specs)
-    bounds = [lo + f * (hi - lo) for f in (0.3, 0.6)]
-    dims = scenario_dims(Scenario("is64", graph, tuple(specs), bounds[0],
-                                  "oracle"))
-    sim = JaxBatchSimulator.padded(
-        [(graph, specs)] * len(bounds), bounds, policy="oracle",
-        use_kernel=impl == "pallas", kernel_interpret=False,
-        pad_dims=tuple(next_pow2(d) for d in dims))
+    scens = [Scenario(graph, g, specs, lo + f * (hi - lo), policy)
+             for f in (0.3, 0.6)]
+    dims = tuple(next_pow2(d) for d in scenario_dims(scens[0]))
+    return build_batch_sim("jax", scens, [None] * len(scens), shared, dims)
+
+
+@pytest.mark.parametrize("policy", ["equal-share", "oracle"])
+@pytest.mark.parametrize("graph", ["is64", "lu64"])
+def test_sweep_stepper_compiles(one_chip, graph, policy):
+    """One NPB bucket at 64 ranks through the stepper the engine
+    dispatches, with the statics it dispatches with."""
+    sim = _bucket(graph, policy)
     args, statics = sim._pack()
-    assert statics["impl"] == impl and statics["interpret"] is False
+    assert set(statics) == {"policy_name", "wants_ticks", "redistribute",
+                            "max_steps", "stacked"}
+    assert statics["stacked"] is (graph == "is64")
     compiled = _run_batch.lower(*_abstract(args, one_chip),
                                 **statics).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == (impl == "pallas")
+    assert "tpu_custom_call" not in compiled.as_text()

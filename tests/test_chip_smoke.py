@@ -1,7 +1,7 @@
 """CPU tests of ``chip_smoke.py``: it refuses to run without a TPU, its
 phases pass their own checks at a tiny size (NPB at 4 ranks, the quick
-mixed family, the Pallas kernel in interpret mode), and the compile
-cache helper picks its directory as documented."""
+mixed family), and the compile cache helper picks its directory as
+documented."""
 
 import json
 import sys
@@ -87,14 +87,6 @@ def test_reference_phase_rejects_a_wrong_result(smoke, clock, npb_sweep):
         smoke.reference_phase(clock, [(rec.scenario, wrong),
                                       (other.scenario, other.result)],
                               max_workers=2)
-
-
-def test_kernel_phase_interpret(smoke, clock, capsys):
-    is4 = [s for s in smoke.npb_scenarios(nodes=(4,))
-           if s.tags["kind"] == "is"]
-    worst = smoke.kernel_phase(clock, is4, native=False)
-    assert worst["dmakespan_s"] <= smoke.MAKESPAN_ATOL
-    assert "kernel_interpret=True" in capsys.readouterr().out
 
 
 def test_service_phase_small(smoke, clock, tmp_path, monkeypatch, capsys):

@@ -307,40 +307,6 @@ class TestDispatchPipeline:
         assert "jit:" in again.backend_summary()
 
 
-class TestInterpretDefault:
-    def test_cpu_defaults_to_interpreter(self):
-        """power_step resolves interpret=None from the backend: the
-        Pallas interpreter on CPU, native lowering elsewhere."""
-        from repro.kernels.power_step import default_interpret
-
-        expected = jax.default_backend() == "cpu"
-        assert default_interpret() is expected
-
-    def test_engine_inherits_backend_default(self):
-        sim = JaxBatchSimulator(listing2_graph(), homogeneous_cluster(3),
-                                [6.0], use_kernel=True)
-        from repro.kernels.power_step import default_interpret
-
-        assert sim.kernel_interpret == default_interpret()
-
-
-class TestKernelEngineParity:
-    def test_use_kernel_matches_ref_engine(self):
-        """The Pallas-kernel engine (interpret mode) and the jnp
-        reference engine walk identical wave sequences."""
-        g = listing2_graph()
-        specs = homogeneous_cluster(3)
-        bounds = [2.5, 6.0, 12.0]
-        for policy in ("equal-share", "oracle"):
-            ref = simulate_batch_jax(g, specs, bounds, policy)
-            ker = simulate_batch_jax(g, specs, bounds, policy,
-                                     use_kernel=True,
-                                     kernel_interpret=True)
-            for a, b in zip(ref, ker):
-                assert b.makespan == pytest.approx(a.makespan, rel=1e-6)
-                assert b.energy_j == pytest.approx(a.energy_j, rel=1e-6)
-
-
 def _two_lane_graph():
     """Same-lane and cross-lane single dependencies, and one job with
     two dependencies on one lane, listed later job first (its threshold

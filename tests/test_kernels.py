@@ -1,5 +1,6 @@
-"""Pallas kernel tests: interpret-mode execution vs pure-jnp oracles,
-swept over shapes and dtypes (deliverable c)."""
+"""Kernel tests: the Pallas kernels in interpret mode vs pure-jnp
+oracles, swept over shapes and dtypes (deliverable c), and the
+wave step vs the numpy backend."""
 
 import jax
 import jax.numpy as jnp
@@ -174,16 +175,39 @@ def _waterfill_case(case, rows=32):
         running = np.concatenate([running, np.zeros((rows, 3), bool)], 1)
     elif case == "distinct-pmax":
         # every lane its own saturation level, floors kept below
-        p_max = np.asarray(tab.p_max) * rng.uniform(1.0, 1.5, (1, n))
+        p_max = np.asarray(tab.p_max) * rng.uniform(1.0, 1.5, n)
         tab = tab._replace(p_max=p_max.astype(np.float32))
         budget = rng.uniform(0.0, float(p_max.sum()), rows)
     return (table, tab, running, budget.astype(np.float32),
             case != "distinct-pmax")
 
 
+def _numpy_step(table, caps, running, remaining, rho, bound,
+                redistribute):
+    """The wave step composed from the numpy backend's pieces, over
+    ``(B, N)`` rows: the idle reclaim and ``batched_waterfill`` (with
+    ``redistribute``), ``batched_operating_point``, ``batched_rates``,
+    the :data:`~repro.kernels.power_step.BIG_TIME` sentinel for
+    rate-less lanes, and the row's power draw and earliest event."""
+    from repro.core.power import batched_operating_point, batched_rates
+    from repro.kernels.power_step import BIG_TIME
+    from repro.policies.vector import batched_waterfill
+
+    if redistribute:
+        idle_draw = ((~running) * table.idle_w).sum(axis=1)
+        caps = batched_waterfill(running, bound - idle_draw, table)
+    freq, duty, power = batched_operating_point(table, caps)
+    rate = np.where(running, batched_rates(table, freq, duty, rho), 0.0)
+    p_node = np.where(running, power, table.idle_w)
+    has_rate = rate > 0
+    t_fin = np.where(has_rate,
+                     remaining / np.where(has_rate, rate, 1.0), BIG_TIME)
+    return rate, t_fin, p_node.sum(axis=1), t_fin.min(axis=1)
+
+
 class TestPowerStep:
-    """Fused power-redistribution step: Pallas (interpret) vs jnp
-    reference, and both vs the numpy translation/waterfill oracles."""
+    """The wave step against the numpy translation/waterfill oracles,
+    and under ``vmap`` as the engine runs it."""
 
     def _tables(self, n=5, seed=0):
         from repro.core.power import heterogeneous_cluster, lut_table
@@ -193,57 +217,82 @@ class TestPowerStep:
         table = lut_table(specs)
         return specs, table, step_tables(table)
 
-    def _inputs(self, table, seed=1):
+    def _inputs(self, table, seed=1, rows=8):
+        """``(caps, running, remaining, rho, bound)`` for ``rows`` rows:
+        ``(rows, N)`` lanes, ``running`` bool, ``bound`` ``(rows,)``."""
         n = table.n_nodes
         rng = np.random.default_rng(seed)
-        caps = rng.uniform(0.2, 1.2 * float(table.p_max.max()), (1, n))
-        running = (rng.random((1, n)) < 0.7).astype(np.float32)
-        remaining = rng.uniform(0.0, 50.0, (1, n))
-        rho = rng.uniform(0.1, 1.0, (1, n))
-        bound = np.array([[rng.uniform(float(table.idle_w.sum()),
-                                       float(table.p_max.sum()))]])
+        caps = rng.uniform(0.2, 1.2 * float(table.p_max.max()), (rows, n))
+        running = rng.random((rows, n)) < 0.7
+        remaining = rng.uniform(0.0, 50.0, (rows, n))
+        rho = rng.uniform(0.1, 1.0, (rows, n))
+        bound = rng.uniform(float(table.idle_w.sum()),
+                            float(table.p_max.sum()), rows)
+        return caps, running, remaining, rho, bound
+
+    @staticmethod
+    def _device(caps, running, remaining, rho, bound):
         f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
-        return tuple(map(f32, (caps, running, remaining, rho, bound)))
+        return (f32(caps), jnp.asarray(running), f32(remaining), f32(rho),
+                f32(bound))
+
+    @staticmethod
+    def _rows_step(redistribute):
+        from repro.kernels.power_step import power_step
+
+        return jax.jit(jax.vmap(
+            lambda tab, c, r, m, h, b: power_step(tab, c, r, m, h, b,
+                                                  redistribute),
+            in_axes=(None, 0, 0, 0, 0, 0)))
 
     @pytest.mark.parametrize("redistribute", [False, True])
     @pytest.mark.parametrize("seed", [1, 2, 3])
-    def test_pallas_matches_ref(self, redistribute, seed):
-        from repro.kernels.power_step import (power_step_pallas,
-                                              power_step_ref)
+    def test_step_matches_numpy_oracle(self, redistribute, seed):
+        """The whole step, row by row, against its numpy composition;
+        the first row runs no lane, so every lane of it and its
+        earliest event are the no-event sentinel."""
+        from repro.kernels.power_step import BIG_TIME
 
         _, table, tab = self._tables()
-        args = self._inputs(table, seed=seed)
-        got = power_step_pallas(tab, *args, redistribute=redistribute,
-                                interpret=True)
-        want = power_step_ref(tab, *args, redistribute=redistribute)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-6, atol=1e-6)
+        inputs = self._inputs(table, seed=seed)
+        inputs[1][0] = False
+        rate, t_fin, p_cl, t_comp, rounds = self._rows_step(redistribute)(
+            tab, *self._device(*inputs))
+        want = _numpy_step(table, *inputs, redistribute=redistribute)
+        for got, w in zip((rate, t_fin, p_cl, t_comp), want):
+            np.testing.assert_allclose(np.asarray(got), w, rtol=1e-5)
+        t_fin = np.asarray(t_fin)
+        assert (t_fin[~inputs[1]] == np.float32(BIG_TIME)).all()
+        assert np.asarray(t_comp)[0] == np.float32(BIG_TIME)
+        rounds = np.asarray(rounds)
+        assert rounds.dtype == np.int32
+        if redistribute:
+            assert rounds[0] == 0 and (rounds[1:] >= 1).all()
+        else:
+            assert (rounds == 0).all()
 
-    def test_pallas_matches_ref_under_vmap(self):
-        """The engine vmaps the kernel over the bound axis; Pallas'
-        batching rule must agree with vmapping the reference."""
-        from repro.kernels.power_step import (power_step_pallas,
-                                              power_step_ref)
+    def test_step_under_vmap_matches_rows(self):
+        """The step vmapped over rows, as the engine runs it, gives the
+        bits of one call per row."""
+        from repro.kernels.power_step import power_step
 
         _, table, tab = self._tables()
-        rows = [self._inputs(table, seed=s) for s in (4, 5, 6)]
-        batched = tuple(jnp.stack(a) for a in zip(*rows))
-        got = jax.vmap(lambda c, r, m, h, b: power_step_pallas(
-            tab, c, r, m, h, b, redistribute=True, interpret=True))(*batched)
-        want = jax.vmap(lambda c, r, m, h, b: power_step_ref(
-            tab, c, r, m, h, b, redistribute=True))(*batched)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-6, atol=1e-6)
+        args = self._device(*self._inputs(table, seed=4))
+        got = self._rows_step(True)(tab, *args)
+        one = jax.jit(lambda *a: power_step(tab, *a, True))
+        for row in range(args[0].shape[0]):
+            want = one(*(a[row] for a in args))
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(g)[row],
+                                              np.asarray(w))
 
     def test_translate_matches_numpy_oracle(self):
-        """The in-kernel gather reproduces batched_operating_point /
+        """The translation reproduces batched_operating_point /
         batched_rates (the numpy backend's translator) on a mixed grid
         of caps, including sub-p_min duty states and ragged LUT pads."""
         from repro.core.power import (batched_operating_point,
                                       batched_rates)
-        from repro.kernels.power_step import power_step_ref
+        from repro.kernels.power_step import power_step, translate_caps
 
         _, table, tab = self._tables()
         n = table.n_nodes
@@ -254,15 +303,18 @@ class TestPowerStep:
         rate_np = batched_rates(table, freq, duty, rho)
         remaining = rng.uniform(0.1, 50.0, (16, n))
         for i in range(16):
-            f32 = lambda a: jnp.asarray(a[i:i + 1], jnp.float32)  # noqa: E731
-            rate, p_node, t_fin, eff, p_cl, t_comp, _ = power_step_ref(
-                tab, f32(caps), jnp.ones((1, n), jnp.float32),
-                f32(remaining), f32(rho), jnp.ones((1, 1), jnp.float32))
-            np.testing.assert_allclose(np.asarray(rate)[0], rate_np[i],
+            f32 = lambda a: jnp.asarray(a[i], jnp.float32)  # noqa: E731
+            rate, t_fin, p_cl, t_comp, _ = power_step(
+                tab, f32(caps), jnp.ones(n, bool), f32(remaining),
+                f32(rho), jnp.float32(1.0), False)
+            np.testing.assert_allclose(np.asarray(rate), rate_np[i],
                                        rtol=1e-5)
-            np.testing.assert_allclose(np.asarray(p_node)[0], power[i],
+            p_node = translate_caps(tab, f32(caps))[2]
+            np.testing.assert_allclose(np.asarray(p_node), power[i],
                                        rtol=1e-5)
-            np.testing.assert_allclose(np.asarray(t_comp)[0, 0],
+            np.testing.assert_allclose(float(p_cl), power[i].sum(),
+                                       rtol=1e-5)
+            np.testing.assert_allclose(float(t_comp),
                                        (remaining[i] / rate_np[i]).min(),
                                        rtol=1e-4)
 
@@ -277,18 +329,17 @@ class TestPowerStep:
         from repro.policies.vector import batched_waterfill
 
         table, tab, running, budget, two_tables = _waterfill_case(case)
-        args = (tab, jnp.asarray(running[:, None, :]),
-                jnp.asarray(budget[:, None, None], jnp.float32))
+        args = (tab, jnp.asarray(running), jnp.asarray(budget, jnp.float32))
         got, rounds = jax.jit(jax.vmap(waterfill_caps,
                                        in_axes=(None, 0, 0)))(*args)
         want = jax.jit(jax.vmap(_unrolled_waterfill,
                                 in_axes=(None, 0, 0)))(*args)
-        got, want = np.asarray(got)[:, 0], np.asarray(want)[:, 0]
+        got, want = np.asarray(got), np.asarray(want)
         if two_tables:
             np.testing.assert_array_equal(got, want)
         else:
             np.testing.assert_array_max_ulp(got, want, maxulp=2)
-        p_max = np.asarray(tab.p_max)[0]
+        p_max = np.asarray(tab.p_max)
         levels = np.array([len(np.unique(p_max[r])) for r in running])
         rounds = np.asarray(rounds)
         assert (rounds <= levels).all()
@@ -299,29 +350,30 @@ class TestPowerStep:
                 rtol=1e-5, atol=1e-5)
 
     @pytest.mark.parametrize("bound_frac", [0.3, 0.7, 1.2])
-    def test_pallas_matches_ref_through_fill_rounds(self, bound_frac):
-        """Oracle waves whose water-fill takes one or two rounds (two
-        node tables): the interpreted kernel and the reference agree,
-        rounds included."""
-        from repro.kernels.power_step import (power_step_pallas,
-                                              power_step_ref)
+    def test_step_through_fill_rounds(self, bound_frac):
+        """Oracle waves at 12 ranks on the two stock tables, whose
+        water-fill takes one or two rounds: the step's reclaimed budget
+        water-fills to batched_waterfill's caps, and its rounds are
+        those of that fill, at least 1 and at most the distinct
+        ``p_max`` levels of the running lanes."""
+        from repro.kernels.power_step import waterfill_caps
+        from repro.policies.vector import batched_waterfill
 
         _, table, tab = self._tables(n=12, seed=4)
-        rows = [self._inputs(table, seed=s) for s in range(10, 16)]
-        caps, running, remaining, rho, _ = (jnp.stack(a)
-                                            for a in zip(*rows))
-        bound = jnp.full((len(rows), 1, 1),
-                         bound_frac * float(table.p_max.sum()), jnp.float32)
-        got = jax.vmap(lambda c, r, m, h, b: power_step_pallas(
-            tab, c, r, m, h, b, redistribute=True, interpret=True))(
-                caps, running, remaining, rho, bound)
-        want = jax.vmap(lambda c, r, m, h, b: power_step_ref(
-            tab, c, r, m, h, b, redistribute=True))(
-                caps, running, remaining, rho, bound)
-        for g, w in zip(got[:-1], want[:-1]):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-6, atol=1e-6)
-        np.testing.assert_array_equal(np.asarray(got[-1]),
-                                      np.asarray(want[-1]))
-        assert np.asarray(want[-1]).max() >= 1
-
+        caps, running, remaining, rho, _ = self._inputs(table, seed=10)
+        bound = np.full(len(caps), bound_frac * float(table.p_max.sum()))
+        *_, rounds = self._rows_step(True)(
+            tab, *self._device(caps, running, remaining, rho, bound))
+        budget = bound - ((~running) * table.idle_w).sum(axis=1)
+        fill, fill_rounds = jax.jit(jax.vmap(
+            waterfill_caps, in_axes=(None, 0, 0)))(
+                tab, jnp.asarray(running), jnp.asarray(budget, jnp.float32))
+        np.testing.assert_allclose(
+            np.asarray(fill), batched_waterfill(running, budget, table),
+            rtol=1e-5, atol=1e-5)
+        rounds = np.asarray(rounds)
+        np.testing.assert_array_equal(rounds, np.asarray(fill_rounds))
+        p_max = np.asarray(tab.p_max)
+        levels = np.array([len(np.unique(p_max[r])) for r in running])
+        assert running.any(axis=1).all()
+        assert ((rounds >= 1) & (rounds <= levels)).all()
